@@ -254,7 +254,8 @@ def main(n=256, n_lon=16, n_lat=8, T=20, delta=2.5, tau=0.4,
                 # (res.n_transpose_copies, from the trace audit).  Only the
                 # Pallas backend ever had a copy at stake, so XLA-backed
                 # runs report 0.
-                pallas = resolve_screen_backend("auto") == "pallas"
+                pallas = resolve_screen_backend(
+                    "auto", problem.X.dtype) == "pallas"
                 emit("path_fig3b", case, "transpose_copies_eliminated",
                      res.n_rounds - res.n_transpose_copies if pallas else 0)
                 if mode == "session":

@@ -17,11 +17,13 @@ from typing import Callable
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs.export import env_meta
 
 # The convex-optimization core targets the paper's 1e-8 duality-gap
 # tolerance, which needs f64 (same switch the tests flip in conftest.py).
 jax.config.update("jax_enable_x64", True)
+enable_compile_cache()
 
 _ROWS: list[tuple[str, str, str, float]] = []
 

@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import SGLSession, SolverConfig, make_problem, lambda_grid
 from repro.data.climate import make_climate_like
 
@@ -29,6 +30,7 @@ N_LON, N_LAT = 16, 8
 
 
 def main():
+    enable_compile_cache()
     X, y, beta_true, sizes = make_climate_like(
         n=256, n_lon=N_LON, n_lat=N_LAT, seed=0
     )

@@ -49,12 +49,14 @@ os.environ.setdefault("JAX_ENABLE_X64", "1")
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import SGLSession, SolverConfig, make_problem
 from repro.data.synthetic import make_synthetic
 from repro.rules import GapSafeRule
 
 
 def main():
+    enable_compile_cache()
     X, y, beta_true, sizes = make_synthetic(
         n=100, p=1000, n_groups=100, gamma1=5, gamma2=4, seed=0
     )

@@ -13,6 +13,7 @@ the perturbed solve's certificates are its own).
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import sgl
 from repro.core.session import SolverConfig, lambda_grid
 from repro.data.synthetic import make_synthetic
@@ -20,6 +21,7 @@ from repro.serve import PathRequest, ServeConfig, SGLServer
 
 
 def main():
+    enable_compile_cache()
     X, y, _beta, sizes = make_synthetic(
         n=64, p=512, n_groups=64, gamma1=3, gamma2=3, seed=11)
     problem = sgl.make_problem(X, y, sizes, tau=0.3)

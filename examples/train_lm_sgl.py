@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs import get
 from repro.models import build
@@ -38,6 +39,7 @@ def synthetic_batch(rng, batch, seq, vocab):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=16)

@@ -23,12 +23,17 @@ is walked (``pjit``/``scan``/``while``/``cond`` bodies included):
   :func:`repro.kernels.ops.note_retrace`, so ``audit_scope`` sees them.
 * **JX005** a ``TypeError`` mentioning hashability while dispatching —
   an unhashable value reached ``static_argnums``.
+* **JX007** a rank-0 f64 ``sub`` whose first operand is a literal
+  (``1.0 - tau``).  A TPU's emulated f64 computes exactly that form to
+  f32 precision only (:mod:`repro.core.precision`); certificate code
+  writes it as :func:`repro.core.precision.one_minus`.
 """
 from __future__ import annotations
 
 from typing import Iterator, List
 
 import jax
+import jax.extend.core as jex_core
 import numpy as np
 
 from ..kernels import ops as kops
@@ -46,14 +51,17 @@ def _as_jaxpr(v):
     return None
 
 
-def iter_eqns(jaxpr) -> Iterator:
+def iter_eqns(jaxpr, skip=()) -> Iterator:
     """All eqns of ``jaxpr`` and every nested sub-jaxpr (pjit bodies, scan/
-    while/cond branches, custom-call closures), depth-first."""
+    while/cond branches, custom-call closures), depth-first.  The bodies
+    of primitives named in ``skip`` are not entered."""
     stack = [jaxpr]
     while stack:
         j = stack.pop()
         for eqn in j.eqns:
             yield eqn
+            if eqn.primitive.name in skip:
+                continue
             for val in eqn.params.values():
                 vals = val if isinstance(val, (list, tuple)) else (val,)
                 for v in vals:
@@ -72,6 +80,12 @@ def _aval_elems(var) -> int:
 
 def _is_float(dt) -> bool:
     return np.issubdtype(np.dtype(dt), np.floating)
+
+
+def _literal_minus_f64_scalar(eqn) -> bool:
+    lhs, out = eqn.invars[0], eqn.outvars[0].aval
+    return (isinstance(lhs, jex_core.Literal) and out.shape == ()
+            and np.dtype(out.dtype) == np.float64)
 
 
 def lint_jaxpr(jaxpr, spec) -> List[Finding]:
@@ -125,6 +139,18 @@ def lint_jaxpr(jaxpr, spec) -> List[Finding]:
                              "out_elements": out_elems,
                              "design_elements": spec.design_elements},
                 ))
+    # XLA programs only: a Pallas body never runs f64 on a TPU (Mosaic has
+    # no 64-bit types), so in f64 it runs interpreted, in IEEE arithmetic.
+    for eqn in iter_eqns(jaxpr, skip=("pallas_call",)):
+        if eqn.primitive.name == "sub" and _literal_minus_f64_scalar(eqn):
+            findings.append(Finding(
+                pass_name="jaxpr", code="JX007",
+                message=("literal minus a rank-0 f64 value: a TPU's "
+                         "emulated f64 rounds it to f32 precision; use "
+                         "repro.core.precision.one_minus"),
+                location=spec.name,
+                details={"literal": float(eqn.invars[0].val)},
+            ))
     return findings
 
 

@@ -17,6 +17,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from .sgl import SGLProblem, make_problem
+from .precision import one_minus
 
 __all__ = ["make_elastic_problem", "elastic_objective"]
 
@@ -53,4 +54,4 @@ def elastic_objective(X_flat, y, beta_flat, tau, w, lam1, lam2, group_sizes):
         l2g = l2g + w[g] * jnp.linalg.norm(beta_flat[off:off + s])
         off += s
     ridge = 0.5 * lam2 * jnp.sum(beta_flat * beta_flat)
-    return fit + lam1 * (tau * l1 + (1.0 - tau) * l2g) + ridge
+    return fit + lam1 * (tau * l1 + one_minus(tau) * l2g) + ridge

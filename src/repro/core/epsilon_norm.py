@@ -36,6 +36,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .precision import one_minus
+
 __all__ = [
     "lam",
     "lam_bisect",
@@ -171,14 +173,14 @@ def lam_bisect(
 def epsilon_norm(x: jax.Array, eps: jax.Array) -> jax.Array:
     """||x||_eps = Lambda(x, 1 - eps, eps)  (paper Eq. 16)."""
     eps = jnp.asarray(eps, jnp.asarray(x).dtype)
-    return lam(x, 1.0 - eps, eps)
+    return lam(x, one_minus(eps), eps)
 
 
 def epsilon_norm_dual(x: jax.Array, eps: jax.Array) -> jax.Array:
     """Dual of the eps-norm: eps ||x|| + (1 - eps) ||x||_1  (paper Lemma 4)."""
     x = jnp.asarray(x)
     eps = jnp.asarray(eps, x.dtype)
-    return eps * jnp.linalg.norm(x, axis=-1) + (1.0 - eps) * jnp.sum(
+    return eps * jnp.linalg.norm(x, axis=-1) + one_minus(eps) * jnp.sum(
         jnp.abs(x), axis=-1
     )
 
@@ -188,6 +190,6 @@ def epsilon_decomposition(x: jax.Array, eps: jax.Array):
     (1-eps)||x||_e  (paper Lemma 1). Returns (x_eps, x_one_minus_eps, nu)."""
     x = jnp.asarray(x)
     nu = epsilon_norm(x, eps)
-    thr = ((1.0 - eps) * nu)[..., None]
+    thr = (one_minus(eps) * nu)[..., None]
     x_eps = jnp.sign(x) * jnp.maximum(jnp.abs(x) - thr, 0.0)
     return x_eps, x - x_eps, nu

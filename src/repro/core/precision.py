@@ -22,6 +22,17 @@ the ``dist_fista/f32-mesh`` entry spec (``min_float_bits=32``).  Enabling
 x64 does not forbid f32 arrays; it only stops f64 requests from being
 silently truncated.
 
+x64 on a TPU is not IEEE f64: XLA emulates it with pairs of f32, and
+Mosaic (Pallas) has no 64-bit types at all.  The emulation is accurate to
+a few hundred f64 ulps in the operations the certificate uses, with one
+exception: ``c - x`` for a constant ``c`` and a rank-0 ``x`` comes out
+to f32 precision only (on a v5e, ``1.0 - tau`` at tau=0.2 is off by
+1.9e-8 relative; ``x - c``, ``c + x`` and negation are exact).  A
+``1 - tau`` that loose moves the SGL norm, the dual norm and the
+Theorem-1 group threshold by ~1.5e-8 relative, which at the paper's
+synthetic size hides duality gaps of up to ~4e-6 under a tol of 1e-8.
+Certificate code therefore writes ``1 - x`` as :func:`one_minus`.
+
 Set ``REPRO_ALLOW_F32=1`` to skip enforcement entirely (e.g. profiling
 runs on accelerators without f64 support); certificates produced under
 that escape hatch are NOT trustworthy and the variable exists so the
@@ -31,7 +42,7 @@ from __future__ import annotations
 
 import os
 
-__all__ = ["ensure_x64"]
+__all__ = ["ensure_x64", "one_minus"]
 
 
 def ensure_x64() -> bool:
@@ -56,3 +67,13 @@ def ensure_x64() -> bool:
             "f32 certificates."
         )
     return True
+
+
+def one_minus(x):
+    """``1 - x``, computed as ``-(x - 1)``.
+
+    The two are bitwise equal under IEEE rounding (up to the sign of a
+    zero), but only the second is exact in a TPU's emulated f64 when ``x``
+    is rank 0 (see the module docstring).
+    """
+    return -(x - 1.0)

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 from . import sgl
 from .epsilon_norm import epsilon_norm, epsilon_norm_dual
 from .sgl import SGLProblem, soft_threshold
+from .precision import one_minus
 
 __all__ = [
     "ScreenResult",
@@ -143,7 +144,7 @@ def dst3_sphere(
     xg = jnp.take(corr, g_star, axis=0) / lam_max       # X_{g*}^T y / lam_max
     eps_s = jnp.take(eps, g_star)
     nu = epsilon_norm(xg, eps_s)
-    xi_star = soft_threshold(xg, (1.0 - eps_s) * nu)    # eps-part of gradient
+    xi_star = soft_threshold(xg, one_minus(eps_s) * nu)  # eps-part of gradient
     denom = epsilon_norm_dual(xi_star, eps_s)
     Xg_star = jnp.take(problem.X, g_star, axis=1)       # (n, ng)
     eta = Xg_star @ xi_star / jnp.maximum(denom, 1e-30)
@@ -232,7 +233,7 @@ def theorem1_tests(
     Tg_out = st_norm + radius * Xnorm_grp
     Tg_in = jnp.maximum(inf_norm + radius * Xnorm_grp - tau, 0.0)
     Tg = jnp.where(inf_norm > tau, Tg_out, Tg_in)
-    group_keep = Tg >= (1.0 - tau) * w                          # keep if test fails
+    group_keep = Tg >= one_minus(tau) * w                        # keep if test fails
 
     feat_keep = jnp.abs(corr) + radius * Xnorm_col >= tau
     return group_keep, feat_keep

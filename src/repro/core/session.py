@@ -469,13 +469,14 @@ class SGLSession:
                 "screening math"
             )
         check_rule_loss(self.rule, self.loss)
-        self.backend = resolve_screen_backend(self.config.screen_backend)
+        self.backend = resolve_screen_backend(self.config.screen_backend,
+                                              problem.X.dtype)
         # Inner-epoch backend (single-device BCD strategy): "pallas" runs
         # whole epoch blocks through the fused kernels/bcd_epoch.py launch,
         # "xla" keeps the lax.scan reference.  Resolved eagerly so an
         # invalid knob fails at session construction, like screen_backend.
         self.solver_backend = resolve_solver_backend(
-            self.config.solver_backend
+            self.config.solver_backend, problem.X.dtype
         )
         self.mesh = mesh
         # Auditable round accounting: every certified round dispatched
@@ -608,14 +609,16 @@ class SGLSession:
                         problem, beta, lam_j, lam_max_j, rule, self.backend,
                         self.xt_pre, loss=loss_arg,
                     )
-            except Exception:
+            except KernelLaunchError:
                 if self.backend != "pallas":
                     raise
                 # Failed Pallas launch: demote the session to the XLA
                 # reference path and retry ONCE.  Bit-parity between the
                 # backends keeps the retried round's outputs identical; the
                 # demotion is counted so a degraded node stays visible in the
-                # fused-launch audit.
+                # fused-launch audit.  Only a launch failure demotes: a
+                # compiler refusal surfaces instead of running XLA under a
+                # Pallas label.
                 self.backend = "xla"
                 self.kernel_demotions += 1
                 kops.note_kernel_demotion()
@@ -1062,7 +1065,7 @@ class SGLSession:
                         beta, k_done, _ = _epochs_compact(
                             self.solver_backend, xt_rows
                         )
-                    except Exception:
+                    except KernelLaunchError:
                         if self.solver_backend != "pallas":
                             raise
                         self._demote_solver_backend()
@@ -1098,7 +1101,7 @@ class SGLSession:
                                     )
                                 beta, resid_nc = beta_b[0], resid_b[0]
                                 self.fused_epoch_launches += 1
-                            except Exception:
+                            except KernelLaunchError:
                                 self._demote_solver_backend()
                                 beta, resid_nc = bcd_epochs(
                                     Xt_full, Lg, problem.w, fmask, beta,
@@ -1130,7 +1133,7 @@ class SGLSession:
                                     )
                                 beta, z_nc = beta_b[0], z_b[0]
                                 self.fused_epoch_launches += 1
-                            except Exception:
+                            except KernelLaunchError:
                                 self._demote_solver_backend()
                                 beta, z_nc = bcd_epochs_loss(
                                     Xt_full, Lg, problem.w, fmask, beta,
@@ -1319,21 +1322,15 @@ class SGLSession:
                         if not done[b]:
                             degraded_b[b] = reason
                     break
-            try:
-                _fire_epoch_launch_fault()
-                with obs_trace.span("epoch_block"), _launch_span("pallas"):
-                    bsub, resid = kops.bcd_epochs_fused(
-                        Xt, Lg_eff, w, fm_b, bsub, resid, problem.tau,
-                        lam_b, block
-                    )
-            except Exception as e:
-                # The batched-lambda driver has no reference twin (the
-                # lax.scan path is per-lambda); a failed fused launch
-                # surfaces as a typed error instead of a silent retry.
-                raise KernelLaunchError(
-                    "batched fused epoch launch failed (no reference twin "
-                    "for the batched driver)"
-                ) from e
+            # The batched-lambda driver has no reference twin (the lax.scan
+            # path is per-lambda): a failed fused launch surfaces as the
+            # KernelLaunchError it raised instead of a silent retry.
+            _fire_epoch_launch_fault()
+            with obs_trace.span("epoch_block"), _launch_span("pallas"):
+                bsub, resid = kops.bcd_epochs_fused(
+                    Xt, Lg_eff, w, fm_b, bsub, resid, problem.tau,
+                    lam_b, block
+                )
             self.fused_epoch_launches += 1
             step += block
             if self.budget is not None:
@@ -1819,9 +1816,13 @@ class _DistStrategy:
         self.fista = jax.jit(self.kernels.fista)
         self.fista_batch = jax.jit(self.kernels.fista_batch)
         self.screen_k = jax.jit(self.kernels.screen)
+        # The design, response and weights live on the mesh, sharded as the
+        # kernels read them (placed once, not resharded per call).
+        self.X, self.y, self.w = self.kernels.place(
+            problem.X, problem.y, problem.w)
         # Design-matrix norms: constants of the problem, computed once per
         # session on the mesh (Frobenius group bound — safe for Thm 1).
-        self.colnorm, self.gfro = jax.jit(self.kernels.norms)(problem.X)
+        self.colnorm, self.gfro = jax.jit(self.kernels.norms)(self.X)
         self.ynorm2 = float(jnp.sum(problem.y * problem.y))
         self.L = float(L) if L is not None else _global_lipschitz(problem)
 
@@ -1836,8 +1837,8 @@ class _DistStrategy:
         s.full_rounds += 1           # sharded rounds are always full-problem
         s.round_flops += 4.0 * problem.n * problem.G * problem.ng
         return self.screen_k(
-            problem.X, problem.y, jnp.asarray(beta, dtype),
-            jnp.asarray(feat_mask, dtype), problem.w,
+            self.X, self.y, jnp.asarray(beta, dtype),
+            jnp.asarray(feat_mask, dtype), self.w,
             self.colnorm, self.gfro,
             jnp.asarray(lam_, dtype), jnp.asarray(self.ynorm2, dtype),
         )
@@ -1971,7 +1972,7 @@ class _DistStrategy:
                 beta = beta * feat_mask
                 z = z * feat_mask
             beta, z, t_mom = self.fista(
-                problem.X, problem.y, beta, z, feat_mask, problem.w, t_mom,
+                self.X, self.y, beta, z, feat_mask, self.w, t_mom,
                 lam_j, jnp.asarray(self.L, dtype),
             )
             n_steps = step + 1
@@ -2039,7 +2040,7 @@ class _DistStrategy:
         while not done.all() and step < max_steps:
             for _ in range(f_ce):
                 beta, z, t_mom = self.fista_batch(
-                    problem.X, problem.y, beta, z, mask, problem.w, t_mom,
+                    self.X, self.y, beta, z, mask, self.w, t_mom,
                     lam_j, jnp.asarray(self.L, dtype),
                 )
             step += f_ce

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from .epsilon_norm import lam
+from .precision import one_minus
 
 __all__ = [
     "SGLProblem",
@@ -232,20 +233,20 @@ def unflatten(problem: SGLProblem, beta_flat: jax.Array) -> jax.Array:
 
 def epsilons(tau: jax.Array, w: jax.Array) -> jax.Array:
     """eps_g = (1-tau) w_g / (tau + (1-tau) w_g)   (paper Eq. 18)."""
-    denom = tau + (1.0 - tau) * w
-    return jnp.where(denom > 0, (1.0 - tau) * w / jnp.where(denom > 0, denom, 1.0), 0.0)
+    denom = tau + one_minus(tau) * w
+    return jnp.where(denom > 0, one_minus(tau) * w / jnp.where(denom > 0, denom, 1.0), 0.0)
 
 
 def group_weight_total(tau: jax.Array, w: jax.Array) -> jax.Array:
     """tau + (1-tau) w_g — the per-group scaling of the eps-norm duality."""
-    return tau + (1.0 - tau) * w
+    return tau + one_minus(tau) * w
 
 
 def sgl_norm(beta: jax.Array, tau, w) -> jax.Array:
     """Omega_{tau,w}(beta) for grouped beta (G, ng) (padding must be zero)."""
     l1 = jnp.sum(jnp.abs(beta))
     l2 = jnp.sum(w * jnp.linalg.norm(beta, axis=-1))
-    return tau * l1 + (1.0 - tau) * l2
+    return tau * l1 + one_minus(tau) * l2
 
 
 def sgl_dual_norm_terms(xi: jax.Array, tau, w) -> jax.Array:
@@ -260,7 +261,7 @@ def sgl_dual_norm_terms(xi: jax.Array, tau, w) -> jax.Array:
     xi = jnp.asarray(xi)
     eps = epsilons(tau, xi.dtype.type(1) * jnp.asarray(w, xi.dtype))
     scale = group_weight_total(tau, jnp.asarray(w, xi.dtype))
-    return lam(xi, 1.0 - eps, eps) / scale
+    return lam(xi, one_minus(eps), eps) / scale
 
 
 def sgl_dual_norm(xi: jax.Array, tau, w) -> jax.Array:
@@ -408,7 +409,7 @@ def multitask_norm(beta: jax.Array, tau, w) -> jax.Array:
     rows = jnp.linalg.norm(beta, axis=-1)           # (G, ng)
     l1 = jnp.sum(rows)
     l2 = jnp.sum(w * jnp.linalg.norm(rows, axis=-1))
-    return tau * l1 + (1.0 - tau) * l2
+    return tau * l1 + one_minus(tau) * l2
 
 
 def multitask_dual_norm_terms(xi: jax.Array, tau, w) -> jax.Array:
@@ -488,7 +489,7 @@ def soft_threshold(x: jax.Array, thr) -> jax.Array:
 def group_soft_threshold(x: jax.Array, thr) -> jax.Array:
     """S^gp_thr(x) = (1 - thr/||x||)_+ x over the trailing axis."""
     nrm = jnp.linalg.norm(x, axis=-1, keepdims=True)
-    scale = jnp.maximum(1.0 - thr / jnp.maximum(nrm, 1e-30), 0.0)
+    scale = jnp.maximum(one_minus(thr / jnp.maximum(nrm, 1e-30)), 0.0)
     return jnp.where(nrm > 0, scale * x, 0.0)
 
 
@@ -502,12 +503,12 @@ def sgl_prox(beta: jax.Array, step, tau, w, lam_) -> jax.Array:
     if step.ndim == 1:
         step = step[:, None]
     a = soft_threshold(beta, tau * lam_ * step)
-    thr = ((1.0 - tau) * lam_ * jnp.asarray(w))[:, None] * step
+    thr = (one_minus(tau) * lam_ * jnp.asarray(w))[:, None] * step
     return group_soft_threshold_keep(a, thr)
 
 
 def group_soft_threshold_keep(x: jax.Array, thr: jax.Array) -> jax.Array:
     """Group soft-threshold with per-group threshold array (G, 1)."""
     nrm = jnp.linalg.norm(x, axis=-1, keepdims=True)
-    scale = jnp.maximum(1.0 - thr / jnp.maximum(nrm, 1e-30), 0.0)
+    scale = jnp.maximum(one_minus(thr / jnp.maximum(nrm, 1e-30)), 0.0)
     return jnp.where(nrm > 0, scale * x, 0.0)

@@ -270,7 +270,7 @@ def bcd_epochs(
     safe_L = jnp.where(Lg > 0, Lg, 1.0)
     step = lam_ / safe_L                              # alpha_g = lam / L_g
     thr1 = tau * step                                 # (Gb,)
-    thr2 = (1.0 - tau) * w * step                     # (Gb,)
+    thr2 = one_minus(tau) * w * step                  # (Gb,)
 
     def group_update(resid, inputs):
         Xg, bg, L, t1, t2, m, lv = inputs
@@ -278,7 +278,7 @@ def bcd_epochs(
         z = (bg + grad_step) * m
         z = jnp.sign(z) * jnp.maximum(jnp.abs(z) - t1, 0.0)
         nrm = jnp.linalg.norm(z)
-        z = jnp.maximum(1.0 - t2 / jnp.maximum(nrm, 1e-30), 0.0) * z
+        z = jnp.maximum(one_minus(t2 / jnp.maximum(nrm, 1e-30)), 0.0) * z
         new_bg = jnp.where(lv > 0, z, bg)
         resid = resid + Xg @ (bg - new_bg)
         return resid, new_bg
@@ -329,7 +329,7 @@ def bcd_epochs_loss(
     safe_L = jnp.where(Lg > 0, Lmaj, 1.0)
     step = lam_ / safe_L
     thr1 = tau * step                                 # (Gb,)
-    thr2 = (1.0 - tau) * w * step                     # (Gb,)
+    thr2 = one_minus(tau) * w * step                  # (Gb,)
 
     def group_update(z, inputs):
         Xg, bg, L, t1, t2, m, lv = inputs
@@ -338,7 +338,7 @@ def bcd_epochs_loss(
         u = (bg + grad_step) * m
         u = jnp.sign(u) * jnp.maximum(jnp.abs(u) - t1, 0.0)
         nrm = jnp.linalg.norm(u)
-        u = jnp.maximum(1.0 - t2 / jnp.maximum(nrm, 1e-30), 0.0) * u
+        u = jnp.maximum(one_minus(t2 / jnp.maximum(nrm, 1e-30)), 0.0) * u
         new_bg = jnp.where(lv > 0, u, bg)
         z = z + Xg @ (new_bg - bg)
         return z, new_bg
@@ -358,32 +358,41 @@ def bcd_epochs_loss(
 # Certified gap + screening round (resumable-round API)
 # ----------------------------------------------------------------------------
 
-def resolve_backend(backend: str, *, what: str = "backend") -> str:
+def resolve_backend(backend: str, dtype, *, what: str = "backend") -> str:
     """Shared backend resolution for every Pallas/XLA dispatch knob.
 
-    ``"auto"`` picks the Pallas kernels on TPU and plain XLA elsewhere
-    (where Pallas would run interpreted); ``"xla"``/``"pallas"`` force.
-    ``what`` only labels the error message (``screen backend`` /
-    ``solver backend``).
+    ``"auto"`` picks the Pallas kernels only where they compile: on TPU and
+    for a problem ``dtype`` of at most 32 bits (Mosaic has no 64-bit
+    types).  Elsewhere it picks plain XLA — on CPU Pallas would only run
+    interpreted, and an f64 problem on TPU runs XLA's emulated f64.
+    ``"xla"``/``"pallas"`` force; forcing ``"pallas"`` on an f64 problem
+    on TPU raises, since no kernel would compile.  ``what`` only labels
+    the error message (``screen backend`` / ``solver backend``).
     """
-    if backend == "auto":
-        return "pallas" if kernel_util.on_tpu() else "xla"
-    if backend not in ("xla", "pallas"):
+    if backend not in ("auto", "xla", "pallas"):
         raise ValueError(f"unknown {what}: {backend!r}")
+    compiles = np.dtype(dtype).itemsize <= 4
+    if backend == "auto":
+        return "pallas" if kernel_util.on_tpu() and compiles else "xla"
+    if backend == "pallas" and kernel_util.on_tpu() and not compiles:
+        raise ValueError(
+            f"{what}='pallas' cannot run a {np.dtype(dtype).name} problem "
+            "on TPU: Mosaic compiles no 64-bit kernel (use 'auto' or 'xla')"
+        )
     return backend
 
 
-def resolve_screen_backend(backend: str) -> str:
+def resolve_screen_backend(backend: str, dtype) -> str:
     """Resolve the screening correlation/dual-norm backend."""
-    return resolve_backend(backend, what="screen backend")
+    return resolve_backend(backend, dtype, what="screen backend")
 
 
-def resolve_solver_backend(backend: str) -> str:
+def resolve_solver_backend(backend: str, dtype) -> str:
     """Resolve the BCD-epoch solver backend (``SolverConfig.solver_backend``):
     ``"pallas"`` runs the inner epochs through the fused
     :mod:`repro.kernels.bcd_epoch` mega-kernel, ``"xla"`` keeps the
     ``lax.scan`` reference (the bit-parity fallback)."""
-    return resolve_backend(backend, what="solver backend")
+    return resolve_backend(backend, dtype, what="solver backend")
 
 
 def _corr_grouped(problem: SGLProblem, v: jax.Array, backend: str,
@@ -668,7 +677,7 @@ def screen_round(
         jnp.asarray(lam_, dtype),
         jnp.asarray(lam_max, dtype),
         rule,
-        resolve_screen_backend(backend),
+        resolve_screen_backend(backend, dtype),
         xt_pre,
         loss=None if loss.name == "lsq" else loss,
     )
@@ -937,6 +946,7 @@ def solve(
 # ----------------------------------------------------------------------------
 
 from ..analysis.registry import register_traceable  # noqa: E402
+from .precision import one_minus
 
 register_traceable("screen_round", _screen_round,
                    module=__name__, kind="jit")

@@ -26,27 +26,12 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import inspect
-
-try:                                     # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                      # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-if "check_vma" in inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    # Older spelling of the replication check is check_rep, regardless of
-    # where the function is exported from.
-    def shard_map(f, *, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        return _shard_map(f, **kwargs)
-
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.sgl import epsilons, group_weight_total, soft_threshold
 from repro.core.epsilon_norm import lam as lam_exact
+from repro.core.precision import one_minus
 
 
 class DistKernels(NamedTuple):
@@ -54,6 +39,7 @@ class DistKernels(NamedTuple):
     screen: object         # certified GAP screen round (Thm 1-2)
     norms: object          # column/group norms of X (compute once)
     fista_batch: object    # batched-lambda FISTA (path points in parallel)
+    place: object          # (X, y, w) -> the same arrays laid out on the mesh
 
 
 class DistSGLState(NamedTuple):
@@ -111,9 +97,9 @@ def make_dist_step(mesh: Mesh, *, tau: float, multi_pod: bool = False,
         u = (z - grad / L) * feat_mask
         # two-level prox at step 1/L
         a = soft_threshold(u, tau * lam_ / L)
-        thr = ((1.0 - tau) * lam_ * w / L)[:, None]
+        thr = (one_minus(tau) * lam_ * w / L)[:, None]
         nrm = jnp.linalg.norm(a, axis=-1, keepdims=True)
-        scale = jnp.maximum(1.0 - thr / jnp.maximum(nrm, 1e-30), 0.0)
+        scale = jnp.maximum(one_minus(thr / jnp.maximum(nrm, 1e-30)), 0.0)
         beta_new = scale * a * feat_mask
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
         z_new = beta_new + ((t - 1.0) / t_new) * (beta_new - beta)
@@ -143,9 +129,9 @@ def make_dist_step(mesh: Mesh, *, tau: float, multi_pod: bool = False,
         u = (z - grad / L) * feat_mask
         step = (lam_ / L)[:, None, None]
         a = soft_threshold(u, tau * step)
-        thr = (1.0 - tau) * step * w[None, :, None]
+        thr = one_minus(tau) * step * w[None, :, None]
         nrm = jnp.linalg.norm(a, axis=-1, keepdims=True)
-        scale = jnp.maximum(1.0 - thr / jnp.maximum(nrm, 1e-30), 0.0)
+        scale = jnp.maximum(one_minus(thr / jnp.maximum(nrm, 1e-30)), 0.0)
         beta_new = scale * a * feat_mask
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
         z_new = beta_new + ((t - 1.0) / t_new)[:, None, None] * (
@@ -191,7 +177,7 @@ def make_dist_step(mesh: Mesh, *, tau: float, multi_pod: bool = False,
 
         eps = epsilons(tau, w)
         scale_g = group_weight_total(tau, w)
-        per_group = lam_exact(corr, 1.0 - eps, eps) / scale_g
+        per_group = lam_exact(corr, one_minus(eps), eps) / scale_g
         dual_norm = jax.lax.pmax(jnp.max(per_group), "model")
         sc = jnp.maximum(lam_, dual_norm)
 
@@ -203,7 +189,7 @@ def make_dist_step(mesh: Mesh, *, tau: float, multi_pod: bool = False,
                           "model")
         # row shards: fit must also psum over data
         fit = jax.lax.psum(fit, dp)
-        primal = fit + lam_ * (tau * l1 + (1.0 - tau) * l2)
+        primal = fit + lam_ * (tau * l1 + one_minus(tau) * l2)
         ydist = jax.lax.psum(
             jnp.sum((resid / sc - y / lam_) ** 2), dp
         )
@@ -221,7 +207,7 @@ def make_dist_step(mesh: Mesh, *, tau: float, multi_pod: bool = False,
             st_norm + r * gfro,
             jnp.maximum(inf_norm + r * gfro - tau, 0.0),
         )
-        gmask = (Tg >= (1.0 - tau) * w).astype(X.dtype)
+        gmask = (Tg >= one_minus(tau) * w).astype(X.dtype)
         fmask = (
             (jnp.abs(corr_t) + r * colnorm >= tau).astype(X.dtype)
             * gmask[:, None]
@@ -229,8 +215,15 @@ def make_dist_step(mesh: Mesh, *, tau: float, multi_pod: bool = False,
         )
         return fmask, gmask, gap, sc
 
+    def place(X, y, w):
+        """Lay the problem out on the mesh once, in the kernels' in_specs;
+        an array left on one device would be resharded on every call."""
+        return tuple(jax.device_put(a, NamedSharding(mesh, spec))
+                     for a, spec in ((X, xspec), (y, yspec), (w, sspec)))
+
     return DistKernels(fista=fista_kernel, screen=screen_kernel,
-                       norms=norms_kernel, fista_batch=fista_batch_kernel)
+                       norms=norms_kernel, fista_batch=fista_batch_kernel,
+                       place=place)
 
 
 def solve_distributed(

@@ -18,6 +18,7 @@ from __future__ import annotations
 from typing import Any, Callable, NamedTuple, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
@@ -93,9 +94,37 @@ class LaunchSpec(NamedTuple):
         return sum(a.array_bytes for a in self.inputs + self.outputs)
 
 
+def _int32_index_map(index_map: Callable[..., Tuple[int, ...]]):
+    """Cast every block index to int32.  With ``jax_enable_x64`` on (the
+    certificate posture, :mod:`repro.core.precision`) a literal ``0`` in an
+    index map traces as int64, which Mosaic refuses to lower."""
+    def mapped(*idx):
+        return tuple(jnp.asarray(v, jnp.int32) for v in index_map(*idx))
+    return mapped
+
+
+def fori_loop_i32(n: int, body, init):
+    """``lax.fori_loop(0, n, body, init)`` with an int32 loop index.
+
+    With static bounds ``fori_loop`` lowers to a scan whose counter starts
+    from a python int, i.e. int64 under x64, and Mosaic cannot lower an
+    int64 -> int32 index conversion (it dies of a ``RecursionError``).
+    Passing ``np.int32`` bounds to ``fori_loop`` does not help: the BCD
+    kernels still fail the same way in the v5e compile rehearsal
+    (``tests/test_tpu_compile.py``).  Same scan, int32 counter.
+    """
+    def step(carry, _):
+        i, x = carry
+        return (i + 1, body(i, x)), None
+
+    (_, out), _ = jax.lax.scan(step, (jnp.int32(0), init), None, length=n)
+    return out
+
+
 def block_specs(arrays) -> list:
     """``pl.BlockSpec`` list for the launch, straight from the ArraySpecs."""
-    return [pl.BlockSpec(a.block, a.index_map) for a in arrays]
+    return [pl.BlockSpec(a.block, _int32_index_map(a.index_map))
+            for a in arrays]
 
 
 def out_shapes(arrays) -> list:

@@ -64,7 +64,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._util import ArraySpec, LaunchSpec, block_specs, default_interpret, out_shapes
+from ._util import (
+    ArraySpec,
+    LaunchSpec,
+    block_specs,
+    default_interpret,
+    fori_loop_i32,
+    out_shapes,
+)
 
 
 def bcd_epoch_launch_spec(
@@ -165,7 +172,7 @@ def _bcd_epoch_kernel(
         beta_ref[0, base + i] = new_bg
         return resid + Xg @ (bg - new_bg)
 
-    resid = jax.lax.fori_loop(0, block_g, group_update, resid)
+    resid = fori_loop_i32(block_g, group_update, resid)
     resid_ref[0, :] = resid
 
 
@@ -324,7 +331,7 @@ def _bcd_epoch_logistic_kernel(
         beta_ref[0, base + i] = new_bg
         return z + Xg @ (new_bg - bg)
 
-    z = jax.lax.fori_loop(0, block_g, group_update, z)
+    z = fori_loop_i32(block_g, group_update, z)
     z_ref[0, :] = z
 
 

@@ -79,6 +79,13 @@ def screening_corr_launch_spec(p: int, n: int, *, block_p: int = 256,
     )
 
 
+def _matvec(xt, theta):
+    """(bp, bn) @ (bn, 1) at full precision.  At the default precision
+    Mosaic feeds f32 to the MXU in one bf16 pass: measured on a v5e, the
+    corr was off by 2e-3 relative."""
+    return jnp.dot(xt, theta, precision=jax.lax.Precision.HIGHEST)
+
+
 def _screening_kernel(xt_ref, theta_ref, corr_ref, st2_ref, *, tau: float, nk: int):
     k = pl.program_id(1)
 
@@ -86,7 +93,7 @@ def _screening_kernel(xt_ref, theta_ref, corr_ref, st2_ref, *, tau: float, nk: i
     def _init():
         corr_ref[...] = jnp.zeros_like(corr_ref)
 
-    corr_ref[...] += xt_ref[...] @ theta_ref[...]      # (bp, bn) @ (bn, 1)
+    corr_ref[...] += _matvec(xt_ref[...], theta_ref[...])
 
     @pl.when(k == nk - 1)
     def _finalize():
@@ -129,7 +136,7 @@ def _corr_kernel(xt_ref, theta_ref, corr_ref, *, nk: int):
     def _init():
         corr_ref[...] = jnp.zeros_like(corr_ref)
 
-    corr_ref[...] += xt_ref[...] @ theta_ref[...]      # (bp, bn) @ (bn, 1)
+    corr_ref[...] += _matvec(xt_ref[...], theta_ref[...])
 
 
 def screening_corr_pallas(

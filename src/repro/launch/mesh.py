@@ -29,6 +29,16 @@ def make_test_mesh() -> Mesh:
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
+def make_local_mesh(data: int, model: int) -> Mesh:
+    """(data, model) mesh over the first ``data * model`` local devices."""
+    need = data * model
+    devs = jax.devices()
+    if len(devs) < need:
+        raise ValueError(f"a {data}x{model} mesh needs {need} devices; "
+                         f"{len(devs)} present")
+    return jax.make_mesh((data, model), ("data", "model"), devices=devs[:need])
+
+
 def translate_spec(spec: P, *, multi_pod: bool) -> P:
     """Map logical 'data' entries to ('pod', 'data') on the multi-pod mesh."""
     if not multi_pod:

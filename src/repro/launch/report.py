@@ -329,6 +329,10 @@ def _fmt_s(x) -> str:
     return f"{x * 1e6:.1f}µs"
 
 
+def _fmt_share(x) -> str:
+    return "not measured" if x is None else f"{float(x):.2e}"
+
+
 def _stage_table(stages: dict, out: list) -> None:
     out.append("| stage | n | p50 | p99 | mean |")
     out.append("|---|---|---|---|---|")
@@ -372,14 +376,13 @@ def render_obs_markdown(payload: dict) -> str:
                 f"| `{name}`{interp} | {_fmt_s(r.get('measured_s'))} | "
                 f"{_fmt_s(r.get('min_s'))} | "
                 f"{r.get('model_flops', 0) / 1e9:.4f} | "
-                f"{a.get('frac_peak_compute', 0):.2e} | "
-                f"{a.get('achieved_vs_model', 0):.2e} | "
-                f"{a.get('model_bottleneck', '—')} |")
+                f"{_fmt_share(a.get('frac_peak_compute'))} | "
+                f"{_fmt_share(a.get('achieved_vs_model'))} | "
+                f"{a.get('model_bottleneck') or '—'} |")
         out.append("")
         if any(r.get("interpret") for r in rows.values()):
             out.append("Interpret-mode rows measure the Pallas emulation "
-                       "on CPU — the achieved-vs-peak column is only "
-                       "meaningful on a real TPU backend.")
+                       "on CPU — their peak shares are not measured.")
             out.append("")
 
     path = sections.get("path")

@@ -9,17 +9,48 @@ bytes are parsed out of the (post-SPMD) HLO text by summing the result-shape
 bytes of every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute op.
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Peaks come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``.  The
+dry-run model targets a TPU v5e (:data:`DRYRUN_KIND`); a measured run is
+compared against the peaks of the device it ran on, and a device with no
+published entry is an error, never a default.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
-PEAK_FLOPS = 197e12       # bf16 per chip
-HBM_BW = 819e9            # bytes/s per chip
-LINK_BW = 50e9            # bytes/s per ICI link
+
+class Peak(NamedTuple):
+    """Published per-chip peaks of one device kind."""
+
+    flops: float      # FLOP/s, bf16 (the MXU's native rate)
+    hbm_bw: float     # bytes/s
+    link_bw: float    # bytes/s per ICI link
+    source: str
+
+
+# Keyed by jax Device.device_kind.  Only published numbers go here.
+PEAKS: Dict[str, Peak] = {
+    "TPU v5 lite": Peak(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '819 GB/s HBM, 1,600 Gbit/s ICI per chip (link_bw: that '
+               'total over the chip\'s 4 ICI links)'),
+}
+
+DRYRUN_KIND = "TPU v5 lite"   # the chip the LM dry-run model assumes
+
+
+def peak_for(device_kind: str) -> Peak:
+    """Published peaks of ``device_kind``; raises for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device kind {device_kind!r}; add it to "
+            "repro.launch.roofline.PEAKS with its source"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -475,18 +506,19 @@ class Roofline:
     collective_bytes: float
     chips: int
     model_flops: Optional[float] = None
+    peak: Peak = PEAKS[DRYRUN_KIND]
 
     @property
     def t_compute(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS)
+        return self.flops / (self.chips * self.peak.flops)
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / (self.chips * HBM_BW)
+        return self.bytes_accessed / (self.chips * self.peak.hbm_bw)
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / (self.chips * LINK_BW)
+        return self.collective_bytes / (self.chips * self.peak.link_bw)
 
     @property
     def bottleneck(self) -> str:
@@ -503,7 +535,7 @@ class Roofline:
         lets us get to the compute roofline."""
         if self.model_flops is None:
             return float("nan")
-        t_useful = self.model_flops / (self.chips * PEAK_FLOPS)
+        t_useful = self.model_flops / (self.chips * self.peak.flops)
         t_bound = max(self.t_compute, self.t_memory, self.t_collective)
         return t_useful / t_bound if t_bound > 0 else float("nan")
 
@@ -527,36 +559,56 @@ class Roofline:
 
 
 def achieved_vs_peak(flops: float, bytes_accessed: float, measured_s: float,
-                     chips: int = 1, collective_bytes: float = 0.0) -> dict:
-    """Measured-wall-clock term next to the dry-run model.
+                     device=None, chips: int = 1,
+                     collective_bytes: float = 0.0) -> dict:
+    """Measured-wall-clock term next to the roofline model.
 
-    Everything above in this module predicts time from HLO costs; this
-    function goes the other way: given a *measured* kernel wall-clock from
-    the :mod:`repro.obs.timing` harness (jit-warm + ``block_until_ready``)
-    and the kernel's model flops / HBM bytes, report the achieved rates as
-    fractions of the hardware-model peaks and of the roofline bound
+    Given a *measured* kernel wall-clock from the :mod:`repro.obs.timing`
+    harness (jit-warm + ``block_until_ready``) and the kernel's model
+    flops / HBM bytes, report the achieved rates as fractions of the peaks
+    of ``device`` (default ``jax.devices()[0]``) and of the roofline bound
     itself.  ``achieved_vs_model`` is ``t_bound / measured`` — 1.0 means
-    the kernel runs exactly at its modeled roofline, smaller means the
-    launch is leaving modeled headroom on the table (interpret-mode CPU
-    runs will be far below 1; the point is that BENCH now carries a
-    measured column at all, per the ROADMAP compiled-kernel item).
+    the kernel runs exactly at its modeled roofline.
+
+    Off TPU the shares are None ("not measured"): a CPU or interpret-mode
+    time is no fraction of any chip's peak.  A TPU whose ``device_kind``
+    has no :data:`PEAKS` entry raises.
     """
-    model = Roofline(flops=flops, bytes_accessed=bytes_accessed,
-                     collective_bytes=collective_bytes, chips=chips)
-    t_bound = max(model.t_compute, model.t_memory, model.t_collective)
     if measured_s <= 0:
         raise ValueError(f"measured_s must be positive, got {measured_s}")
-    return {
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    out = {
         "measured_s": measured_s,
         "achieved_flops_per_s": flops / measured_s,
         "achieved_bytes_per_s": bytes_accessed / measured_s,
-        "frac_peak_compute": (flops / measured_s) / (chips * PEAK_FLOPS),
-        "frac_peak_memory": (bytes_accessed / measured_s) / (chips * HBM_BW),
+        "device_kind": device.device_kind,
+        "frac_peak_compute": None,
+        "frac_peak_memory": None,
+        "model_t_compute_s": None,
+        "model_t_memory_s": None,
+        "model_bottleneck": None,
+        "achieved_vs_model": None,
+    }
+    if device.platform != "tpu":
+        return out
+    peak = peak_for(device.device_kind)
+    model = Roofline(flops=flops, bytes_accessed=bytes_accessed,
+                     collective_bytes=collective_bytes, chips=chips,
+                     peak=peak)
+    t_bound = max(model.t_compute, model.t_memory, model.t_collective)
+    out.update({
+        "frac_peak_compute": (flops / measured_s) / (chips * peak.flops),
+        "frac_peak_memory": (bytes_accessed / measured_s)
+                            / (chips * peak.hbm_bw),
         "model_t_compute_s": model.t_compute,
         "model_t_memory_s": model.t_memory,
         "model_bottleneck": model.bottleneck,
         "achieved_vs_model": (t_bound / measured_s) if t_bound > 0 else None,
-    }
+    })
+    return out
 
 
 def count_params(param_structs) -> int:

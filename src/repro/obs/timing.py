@@ -34,8 +34,8 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import numpy as np
 
-from ..kernels import ops as kops
 from ..kernels._util import on_tpu
+from ..kernels.cases import kernel_cases
 from ..launch.roofline import achieved_vs_peak
 from . import metrics as obs_metrics
 
@@ -46,134 +46,101 @@ _M_MEASURED = obs_metrics.REGISTRY.histogram(
 
 
 class TimingCase(NamedTuple):
-    """One timed kernel: thunk builder + flops/bytes model.
+    """One timed kernel: geometry per scale + flops/bytes model.
 
-    ``build(scale)`` returns ``(fn, args, flops, bytes)`` — ``fn(*args)``
-    is exactly the dispatch wrapper the solver calls.
+    The arguments come from :func:`repro.kernels.cases.kernel_cases` at
+    ``geometry(scale)``, so ``fn(*args)`` is exactly the dispatch wrapper
+    the solver calls.  ``model(geometry, itemsize)`` returns the hand
+    model ``(flops, bytes)`` of one call.
     """
 
     audit_name: str
-    build: Callable[[str], Tuple[Callable, tuple, float, float]]
+    kernel: str
+    geometry: Callable[[str], dict]
+    model: Callable[[dict, int], Tuple[float, float]]
+
+    def build(self, scale: str, dtype) -> Tuple[Callable, tuple, float,
+                                                 float]:
+        geo = self.geometry(scale)
+        case = kernel_cases(dtype=dtype, **geo)[self.kernel]
+        args = case.make_args(jax.random.PRNGKey(0))
+        flops, bts = self.model(geo, np.dtype(dtype).itemsize)
+        return case.fn, args, flops, bts
 
 
-def _rng():
-    return np.random.default_rng(0)
+def _corr_geom(scale: str) -> dict:
+    # p = G * ng rows of the transposed design
+    return (dict(n=256, G=64, ng=8) if scale == "smoke"
+            else dict(n=1024, G=512, ng=8))
 
 
-def _f64(a):
-    return jax.numpy.asarray(np.asarray(a, dtype=np.float64))
+def _group_geom(scale: str) -> dict:
+    return dict(n=1, G=512 if scale == "smoke" else 4096, ng=8)
 
 
-def _corr_shape(scale: str) -> Tuple[int, int]:
-    return (512, 256) if scale == "smoke" else (4096, 1024)
-
-
-def _build_corr(scale: str):
-    p, n = _corr_shape(scale)
-    r = _rng()
-    Xt = _f64(r.standard_normal((p, n)))
-    theta = _f64(r.standard_normal(n))
-    # matvec: 2 flops per (p, n) cell; traffic: design + vector + result
-    flops = 2.0 * p * n
-    bts = 8.0 * (p * n + n + p)
-    return kops.screening_corr, (Xt, theta), flops, bts
-
-
-def _build_scores(scale: str):
-    p, n = _corr_shape(scale)
-    r = _rng()
-    Xt = _f64(r.standard_normal((p, n)))
-    theta = _f64(r.standard_normal(n))
-    fn = lambda Xt, th: kops.screening_scores(Xt, th, 0.3)  # noqa: E731
-    # corr matvec + fused soft-threshold square (~4 flops/row)
-    flops = 2.0 * p * n + 4.0 * p
-    bts = 8.0 * (p * n + n + 2 * p)
-    return fn, (Xt, theta), flops, bts
-
-
-def _build_dual_norm(scale: str):
-    G = 512 if scale == "smoke" else 4096
-    ng, n_iter = 8, 64
-    r = _rng()
-    x = _f64(r.standard_normal((G, ng)))
-    alpha = _f64(np.full(G, 0.7))
-    R = _f64(np.full(G, 0.3))
-    fn = lambda x, a, R: kops.dual_norm_groups(x, a, R, n_iter=n_iter)  # noqa: E731
-    # bisection: ~4 flops per feature per iteration (shrink, square, sum)
-    flops = 4.0 * G * ng * n_iter
-    bts = 8.0 * (G * ng + 3 * G)
-    return fn, (x, alpha, R), flops, bts
-
-
-def _build_prox(scale: str):
-    G = 512 if scale == "smoke" else 4096
-    ng = 8
-    r = _rng()
-    beta = _f64(r.standard_normal((G, ng)))
-    step = _f64(np.full(G, 0.05))
-    w = _f64(np.ones(G))
-    fn = lambda b, s, w: kops.sgl_prox(b, s, w, 0.3, 1.0)  # noqa: E731
-    # two-level prox: ~6 flops per feature (shrink + norm + group scale)
-    flops = 6.0 * G * ng
-    bts = 8.0 * (2 * G * ng + 2 * G)
-    return fn, (beta, step, w), flops, bts
-
-
-def _bcd_geom(scale: str, bucket: bool):
+def _bcd_geom(scale: str, bucket: bool) -> dict:
     if scale == "smoke":
-        return (2 if bucket else 1), 16, 128, (16 if bucket else 8), 2
-    return ((4, 256, 1024, 16, 3) if bucket else (1, 64, 2048, 8, 2))
+        return dict(B=2 if bucket else 1, G=16, n=128,
+                    ng=16 if bucket else 8, n_epochs=2)
+    return (dict(B=4, G=256, n=1024, ng=16, n_epochs=3) if bucket
+            else dict(B=1, G=64, n=2048, ng=8, n_epochs=2))
 
 
-def _bcd_inputs(B, Gb, n, ng):
-    r = _rng()
-    Xt = _f64(r.standard_normal((Gb, n, ng)))
-    Lg = _f64(np.sum(np.asarray(Xt) ** 2, axis=(1, 2)) / ng + 1.0)
-    w = _f64(np.ones(Gb))
-    fmask = _f64(np.ones((B, Gb, ng)))
-    beta = _f64(0.01 * r.standard_normal((B, Gb, ng)))
-    lam_b = _f64(np.full(B, 0.1))
-    return Xt, Lg, w, fmask, beta, lam_b
+def _corr_model(g, s):
+    # matvec: 2 flops per (p, n) cell; traffic: design + vector + result
+    p, n = g["G"] * g["ng"], g["n"]
+    return 2.0 * p * n, s * (p * n + n + p)
 
 
-def _build_bcd(scale: str, bucket: bool):
-    B, Gb, n, ng, E = _bcd_geom(scale, bucket)
-    Xt, Lg, w, fmask, beta, lam_b = _bcd_inputs(B, Gb, n, ng)
-    resid = _f64(_rng().standard_normal((B, n)))
-    fn = lambda *a: kops.bcd_epochs_fused(*a, n_epochs=E, block_g=8)  # noqa: E731
-    args = (Xt, Lg, w, fmask, beta, resid, 0.3, lam_b)
-    # per epoch, group: corr (2·n·ng) + residual rank-1 update (2·n·ng)
-    flops = 4.0 * E * B * Gb * n * ng
+def _scores_model(g, s):
+    # corr matvec + fused soft-threshold square (~4 flops/row)
+    p, n = g["G"] * g["ng"], g["n"]
+    return 2.0 * p * n + 4.0 * p, s * (p * n + n + 2 * p)
+
+
+def _dual_norm_model(g, s, n_iter=64):
+    # bisection: ~4 flops per feature per iteration (shrink, square, sum)
+    G, ng = g["G"], g["ng"]
+    return 4.0 * G * ng * n_iter, s * (G * ng + 3 * G)
+
+
+def _prox_model(g, s):
+    # two-level prox: ~6 flops per feature (shrink + norm + group scale)
+    G, ng = g["G"], g["ng"]
+    return 6.0 * G * ng, s * (2 * G * ng + 2 * G)
+
+
+def _bcd_model(g, s):
+    # per epoch, group: corr (2·n·ng) + residual rank-1 update (2·n·ng);
     # design streamed once per epoch; state read+written once
-    bts = 8.0 * (E * Gb * n * ng + 2 * (B * Gb * ng + B * n))
-    return fn, args, flops, bts
+    B, G, n, ng, E = g["B"], g["G"], g["n"], g["ng"], g["n_epochs"]
+    return (4.0 * E * B * G * n * ng,
+            s * (E * G * n * ng + 2 * (B * G * ng + B * n)))
 
 
-def _build_bcd_logistic(scale: str):
-    B, Gb, n, ng, E = _bcd_geom(scale, bucket=True)
-    Xt, Lg, w, fmask, beta, lam_b = _bcd_inputs(B, Gb, n, ng)
-    r = _rng()
-    z = _f64(0.1 * r.standard_normal((B, n)))
-    y = _f64((r.standard_normal(n) > 0).astype(np.float64))
-    fn = lambda *a: kops.bcd_epochs_logistic_fused(  # noqa: E731
-        *a, n_epochs=E, block_g=8)
-    args = (Xt, Lg, w, fmask, beta, z, y, 0.3, lam_b)
+def _bcd_logistic_model(g, s):
     # lsq-epoch work + sigmoid/gradient on the carry (~8 flops per sample)
-    flops = 4.0 * E * B * Gb * n * ng + 8.0 * E * B * Gb * n
-    bts = 8.0 * (E * Gb * n * ng + 2 * (B * Gb * ng + B * n) + n)
-    return fn, args, flops, bts
+    flops, bts = _bcd_model(g, s)
+    B, G, n, E = g["B"], g["G"], g["n"], g["n_epochs"]
+    return flops + 8.0 * E * B * G * n, bts + s * n
 
 
 #: One timed case per registered kernel-audit family (names match
 #: repro.kernels.ops register_kernel_audit entries).
 CASES: Tuple[TimingCase, ...] = (
-    TimingCase("bcd_epoch/bucket", lambda s: _build_bcd(s, bucket=True)),
-    TimingCase("bcd_epoch/paper-ng8", lambda s: _build_bcd(s, bucket=False)),
-    TimingCase("bcd_epoch_logistic/bucket", _build_bcd_logistic),
-    TimingCase("screening_scores/default", _build_scores),
-    TimingCase("screening_corr/default", _build_corr),
-    TimingCase("dual_norm/paper-ng8", _build_dual_norm),
-    TimingCase("sgl_prox/paper-ng8", _build_prox),
+    TimingCase("bcd_epoch/bucket", "bcd_epoch",
+               lambda s: _bcd_geom(s, bucket=True), _bcd_model),
+    TimingCase("bcd_epoch/paper-ng8", "bcd_epoch",
+               lambda s: _bcd_geom(s, bucket=False), _bcd_model),
+    TimingCase("bcd_epoch_logistic/bucket", "bcd_epoch_logistic",
+               lambda s: _bcd_geom(s, bucket=True), _bcd_logistic_model),
+    TimingCase("screening_scores/default", "screening_scores",
+               _corr_geom, _scores_model),
+    TimingCase("screening_corr/default", "screening_corr",
+               _corr_geom, _corr_model),
+    TimingCase("dual_norm/paper-ng8", "dual_norm",
+               _group_geom, _dual_norm_model),
+    TimingCase("sgl_prox/paper-ng8", "sgl_prox", _group_geom, _prox_model),
 )
 
 
@@ -193,8 +160,12 @@ def measure_one(fn: Callable, args: tuple, warmup: int = 2,
 
 
 def measure_kernels(scale: str = "smoke", warmup: int = 2, repeat: int = 5,
-                    names: Optional[Tuple[str, ...]] = None) -> Dict[str, dict]:
+                    names: Optional[Tuple[str, ...]] = None,
+                    dtype=None) -> Dict[str, dict]:
     """Run the harness over every (or the named) registered kernel case.
+
+    ``dtype`` defaults to f32 on a TPU (Mosaic compiles no 64-bit kernel)
+    and f64 elsewhere.
 
     Returns per-kernel rows ready for the BENCH ``kernels`` section:
     measured wall-clock, model flops/bytes, the audited LaunchSpec's VMEM
@@ -203,16 +174,19 @@ def measure_kernels(scale: str = "smoke", warmup: int = 2, repeat: int = 5,
     from ..analysis.registry import kernel_audits
 
     audits = kernel_audits()
+    if dtype is None:
+        dtype = np.float32 if on_tpu() else np.float64
     out: Dict[str, dict] = {}
     for case in CASES:
         if names is not None and case.audit_name not in names:
             continue
-        fn, args, flops, bts = case.build(scale)
+        fn, args, flops, bts = case.build(scale, dtype)
         t = measure_one(fn, args, warmup=warmup, repeat=repeat)
         _M_MEASURED.observe(t["median_s"])
         row = {
             "scale": scale,
             "interpret": not on_tpu(),
+            "dtype": np.dtype(dtype).name,
             "measured_s": t["median_s"],
             "min_s": t["min_s"],
             "model_flops": flops,

@@ -140,8 +140,10 @@ class SessionCache:
 
     def _build(self, problem: SGLProblem, config: SolverConfig) -> SGLSession:
         xt_pre = None
-        needs_xt = (resolve_screen_backend(config.screen_backend) == "pallas"
-                    or resolve_solver_backend(config.solver_backend)
+        dtype = problem.X.dtype
+        needs_xt = (resolve_screen_backend(config.screen_backend, dtype)
+                    == "pallas"
+                    or resolve_solver_backend(config.solver_backend, dtype)
                     == "pallas")
         # capacity=0 means fully cold: no design reuse either, so the
         # no-cache baseline really rebuilds everything per request.

@@ -238,6 +238,20 @@ def test_jx001_dtype_demotion_fires():
     assert codes(fs) == []
 
 
+def test_jx007_literal_minus_f64_scalar_fires():
+    from repro.core.precision import one_minus
+
+    fs = jaxpr_lints.lint_entry_point(
+        _spec(lambda t: 1.0 - t, jnp.float64(0.2)))
+    assert codes(fs) == ["JX007"]
+    # the exact spelling, vectors and f32 scalars stay legal
+    for fn, x in ((one_minus, jnp.float64(0.2)),
+                  (lambda t: 1.0 - t, jnp.ones(4, jnp.float64)),
+                  (lambda t: 1.0 - t, jnp.float32(0.2))):
+        assert codes(jaxpr_lints.lint_entry_point(_spec(fn, x))) == []
+    assert one_minus(jnp.float64(0.2)) == 1.0 - jnp.float64(0.2)
+
+
 def test_jx002_design_sized_transpose_fires():
     x = jnp.ones((8, 16), jnp.float64)
 

@@ -97,11 +97,11 @@ def test_fused_epochs_zero_epochs_is_identity(rng):
 
 
 def test_resolve_solver_backend_validates():
-    assert resolve_solver_backend("xla") == "xla"
-    assert resolve_solver_backend("pallas") == "pallas"
-    assert resolve_solver_backend("auto") in ("xla", "pallas")
+    assert resolve_solver_backend("xla", np.float64) == "xla"
+    assert resolve_solver_backend("pallas", np.float64) == "pallas"
+    assert resolve_solver_backend("auto", np.float32) in ("xla", "pallas")
     with pytest.raises(ValueError, match="solver backend"):
-        resolve_solver_backend("cuda")
+        resolve_solver_backend("cuda", np.float32)
     with pytest.raises(ValueError, match="solver backend"):
         SGLSession(
             sgl.make_problem(np.eye(4), np.ones(4), [2, 2], tau=0.5),

@@ -8,8 +8,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from types import SimpleNamespace
+
 from repro.launch.roofline import (
-    Roofline, analyze_hlo, parse_collective_bytes,
+    PEAKS, Roofline, achieved_vs_peak, analyze_hlo, parse_collective_bytes,
 )
 
 D = 128
@@ -94,3 +96,28 @@ ENTRY %main (x: f32[16]) -> f32[16] {
     c = parse_collective_bytes(hlo)
     assert c["all-gather"] == 64 * 4
     assert c["all-reduce"] == 16 * 4
+
+
+def test_achieved_vs_peak_on_cpu_is_not_measured():
+    cpu = SimpleNamespace(platform="cpu", device_kind="cpu")
+    a = achieved_vs_peak(2e9, 1e9, 0.5, device=cpu)
+    assert a["achieved_flops_per_s"] == pytest.approx(4e9)
+    for key in ("frac_peak_compute", "frac_peak_memory",
+                "achieved_vs_model", "model_bottleneck"):
+        assert a[key] is None
+
+
+def test_achieved_vs_peak_uses_the_device_kind_peak():
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    peak = PEAKS["TPU v5 lite"]
+    a = achieved_vs_peak(peak.flops, peak.hbm_bw / 2, 1.0, device=v5e)
+    assert a["frac_peak_compute"] == pytest.approx(1.0)
+    assert a["frac_peak_memory"] == pytest.approx(0.5)
+    assert a["model_bottleneck"] == "compute"
+    assert a["achieved_vs_model"] == pytest.approx(1.0)
+
+
+def test_achieved_vs_peak_unknown_tpu_kind_raises():
+    unknown = SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        achieved_vs_peak(1e9, 1e9, 1.0, device=unknown)

@@ -309,3 +309,5 @@ def test_unknown_backend_raises_at_config_construction():
     # the valid values (and _replace) still construct fine
     cfg = SolverConfig(screen_backend="pallas", solver_backend="xla")
     assert cfg._replace(tol=1e-6).screen_backend == "pallas"
+
+
