@@ -139,11 +139,10 @@ from ..obs import trace as obs_trace
 from ..rules import ScreeningRule, resolve_rule
 
 
-def _launch_span(backend: str):
-    """A ``kernel_launch`` span for Pallas dispatches; the XLA reference
-    path gets the no-op singleton so span counts tally fused launches."""
-    return (obs_trace.span("kernel_launch") if backend == "pallas"
-            else obs_trace.NOOP)
+def _read(what: str):
+    """A ``read`` span around one blocking device->host read."""
+    return obs_trace.span("read").set("what", what)
+
 
 __all__ = [
     "SolverConfig",
@@ -604,11 +603,10 @@ class SGLSession:
                         raise KernelLaunchError(
                             "injected screening-kernel launch failure"
                         )
-                with _launch_span(self.backend):
-                    res, resid, terms = _screen_round(
-                        problem, beta, lam_j, lam_max_j, rule, self.backend,
-                        self.xt_pre, loss=loss_arg,
-                    )
+                res, resid, terms = _screen_round(
+                    problem, beta, lam_j, lam_max_j, rule, self.backend,
+                    self.xt_pre, loss=loss_arg,
+                )
             except KernelLaunchError:
                 if self.backend != "pallas":
                     raise
@@ -640,7 +638,9 @@ class SGLSession:
                 # gap through the same dataflow; mirror that so the gap
                 # stays the universal corruption detector.
                 res = res._replace(gap=res.gap * bad)
-        if np.isfinite(float(res.gap)):
+        with _read("gap"):
+            finite = np.isfinite(float(res.gap))
+        if finite:
             caches.set_refs(problem, resid, terms)
         else:
             self.nonfinite_rounds += 1
@@ -677,18 +677,19 @@ class SGLSession:
         dtype = problem.X.dtype
         with obs_trace.span("round") as _sp:
             _sp.set("compact", True)
-            with _launch_span(self.backend):
-                gap, theta, g_keep, f_keep, valid = _screen_round_compact(
-                    problem, Xt, take, gmask,
-                    jnp.asarray(beta, dtype),
-                    jnp.asarray(feat_active),
-                    jnp.asarray(group_active),
-                    caches.ref_terms, caches.resid_ref, lam_j,
-                    self.backend, xt_rows,
-                )
+            gap, theta, g_keep, f_keep, valid = _screen_round_compact(
+                problem, Xt, take, gmask,
+                jnp.asarray(beta, dtype),
+                jnp.asarray(feat_active),
+                jnp.asarray(group_active),
+                caches.ref_terms, caches.resid_ref, lam_j,
+                self.backend, xt_rows,
+            )
         # Attempt cost is spent either way (honest FLOP accounting).
         self.round_flops += 4.0 * problem.n * Xt.shape[0] * problem.ng
-        if not bool(valid):
+        with _read("valid"):
+            valid = bool(valid)
+        if not valid:
             self.compact_fallbacks += 1
             return None
         self.rounds += 1
@@ -832,8 +833,9 @@ class SGLSession:
                                              # stateless solve() recomputed
                                              # this O(n p) dual norm per call
 
-        group_active = np.array(jnp.any(problem.feat_mask, axis=-1))
-        feat_active = np.array(problem.feat_mask)
+        with _read("problem"):
+            group_active = np.array(jnp.any(problem.feat_mask, axis=-1))
+            feat_active = np.array(problem.feat_mask)
 
         # Pre-screening rules (static sphere) screen once, up front —
         # through the same backend-routed Theorem-1 tests as every round,
@@ -865,8 +867,9 @@ class SGLSession:
         gap = jnp.inf
         round_res = first_round
         lam_max_j = jnp.asarray(lam_max, dtype)
-        n_real_groups = int(np.asarray(
-            jnp.any(problem.feat_mask, axis=-1)).sum())
+        with _read("problem"):
+            n_real_groups = int(np.asarray(
+                jnp.any(problem.feat_mask, axis=-1)).sum())
         # Non-compact branch state, hoisted out of the round loop: ONE
         # transposed design for the whole solve and a carried residual —
         # the loop used to re-materialise a fresh (G, n, ng) copy of X and
@@ -933,15 +936,18 @@ class SGLSession:
                         # rho, not z — drop the carried predictor so it is
                         # recomputed from beta (same drift-reset cadence).
                         z_nc = None
-            if bool(round_res.compact) and float(round_res.gap) <= tol:
+            if bool(round_res.compact):
                 # The REPORTED gap/certificate must always be full-problem
                 # exact: re-confirm an (exact, but buffer-computed)
                 # compact-round convergence with a full round before
                 # stopping.  If the full gap disagrees (> tol), the loop
                 # simply continues from the full round.
-                round_res = self._certified_round(
-                    beta, lam_j, lam_max_j, rule, caches=caches
-                )
+                with _read("gap"):
+                    compact_done = float(round_res.gap) <= tol
+                if compact_done:
+                    round_res = self._certified_round(
+                        beta, lam_j, lam_max_j, rule, caches=caches
+                    )
             gap_r, theta_r = round_res.gap, round_res.theta
             g_act, f_act = round_res.group_active, round_res.feat_active
             round_res = None
@@ -963,7 +969,9 @@ class SGLSession:
                         f"rounds at lambda={float(lam_):.3e}; rewind could "
                         "not recover a finite trajectory"
                     )
-                if not bool(jnp.all(jnp.isfinite(beta))):
+                with _read("finite"):
+                    beta_finite = bool(jnp.all(jnp.isfinite(beta)))
+                if not beta_finite:
                     beta = (best_beta if best_beta is not None
                             else jnp.zeros((G, ng), dtype))
                     resid_nc = None
@@ -993,32 +1001,36 @@ class SGLSession:
                     break
 
             if rule.is_dynamic:
-                n_g0 = int(group_active.sum())
-                n_f0 = int(feat_active.sum())
-                group_active &= np.asarray(g_act)
-                feat_active &= np.asarray(f_act)
-                feat_active &= group_active[:, None]
-                masks_changed = (int(group_active.sum()) != n_g0
-                                 or int(feat_active.sum()) != n_f0)
-                beta_masked = beta * jnp.asarray(feat_active, dtype)
-                if resid_nc is not None and masks_changed:
-                    # Keep the carried residual consistent with the newly
-                    # zeroed coefficients (masks shrink monotonically, so
-                    # an unchanged mask leaves beta — and resid — as-is).
-                    if Xt_full is None:
-                        Xt_full = jnp.transpose(problem.X, (1, 0, 2))
-                    resid_nc = resid_nc + jnp.einsum(
-                        "gnk,gk->n", Xt_full, beta - beta_masked
-                    )
-                if z_nc is not None and masks_changed:
-                    # Same consistency rule for the generic-loss predictor
-                    # carry: z = X beta shrinks by X (beta - beta_masked).
-                    if Xt_full is None:
-                        Xt_full = jnp.transpose(problem.X, (1, 0, 2))
-                    z_nc = z_nc - jnp.einsum(
-                        "gnk,gk->n", Xt_full, beta - beta_masked
-                    )
-                beta = beta_masked
+                with obs_trace.span("masks"):
+                    n_g0 = int(group_active.sum())
+                    n_f0 = int(feat_active.sum())
+                    with _read("masks"):
+                        group_active &= np.asarray(g_act)
+                        feat_active &= np.asarray(f_act)
+                    feat_active &= group_active[:, None]
+                    masks_changed = (int(group_active.sum()) != n_g0
+                                     or int(feat_active.sum()) != n_f0)
+                    beta_masked = beta * jnp.asarray(feat_active, dtype)
+                    if resid_nc is not None and masks_changed:
+                        # Keep the carried residual consistent with the
+                        # newly zeroed coefficients (masks shrink
+                        # monotonically, so an unchanged mask leaves beta —
+                        # and resid — as-is).
+                        if Xt_full is None:
+                            Xt_full = jnp.transpose(problem.X, (1, 0, 2))
+                        resid_nc = resid_nc + jnp.einsum(
+                            "gnk,gk->n", Xt_full, beta - beta_masked
+                        )
+                    if z_nc is not None and masks_changed:
+                        # Same consistency rule for the generic-loss
+                        # predictor carry: z = X beta shrinks by
+                        # X (beta - beta_masked).
+                        if Xt_full is None:
+                            Xt_full = jnp.transpose(problem.X, (1, 0, 2))
+                        z_nc = z_nc - jnp.einsum(
+                            "gnk,gk->n", Xt_full, beta - beta_masked
+                        )
+                    beta = beta_masked
 
             active_history.append(
                 (epochs_done, int(group_active.sum()),
@@ -1043,22 +1055,21 @@ class SGLSession:
                 def _epochs_compact(backend, rows):
                     if backend == "pallas":
                         _fire_epoch_launch_fault()
-                    with _launch_span(backend):
-                        if lsq:
-                            return _inner_rounds(
-                                Xt, Lg, w, problem.y, beta,
-                                jnp.asarray(feat_active),
-                                take, gmask, problem.tau, lam_j,
-                                jnp.asarray(tol, dtype), check, max_blocks,
-                                backend, rows
-                            )
-                        return _inner_rounds_loss(
+                    if lsq:
+                        return _inner_rounds(
                             Xt, Lg, w, problem.y, beta,
                             jnp.asarray(feat_active),
                             take, gmask, problem.tau, lam_j,
-                            jnp.asarray(tol, dtype), self.loss, check,
-                            max_blocks, backend, rows
+                            jnp.asarray(tol, dtype), check, max_blocks,
+                            backend, rows
                         )
+                    return _inner_rounds_loss(
+                        Xt, Lg, w, problem.y, beta,
+                        jnp.asarray(feat_active),
+                        take, gmask, problem.tau, lam_j,
+                        jnp.asarray(tol, dtype), self.loss, check,
+                        max_blocks, backend, rows
+                    )
 
                 with obs_trace.span("epoch_block"):
                     try:
@@ -1070,14 +1081,16 @@ class SGLSession:
                             raise
                         self._demote_solver_backend()
                         beta, k_done, _ = _epochs_compact("xla", None)
-                epochs_done += check * int(k_done)
+                with _read("k_done"):
+                    k_done = int(k_done)
+                epochs_done += check * k_done
                 if self.solver_backend == "pallas" and (
                         lsq or self.loss.name == "logistic"):
                     # Each inner block ran as ONE fused kernel launch
                     # (k_done of them) instead of O(G) scan steps.  Other
                     # generic losses fall back to the lax.scan epochs
                     # inside _inner_rounds_loss — no fused launch to count.
-                    self.fused_epoch_launches += int(k_done)
+                    self.fused_epoch_launches += k_done
             else:
                 if Xt_full is None:
                     Xt_full = jnp.transpose(problem.X, (1, 0, 2))
@@ -1092,13 +1105,12 @@ class SGLSession:
                         with obs_trace.span("epoch_block"):
                             try:
                                 _fire_epoch_launch_fault()
-                                with _launch_span("pallas"):
-                                    beta_b, resid_b = kops.bcd_epochs_fused(
-                                        Xt_full, Lg, problem.w, fmask[None],
-                                        beta[None], resid_nc[None],
-                                        problem.tau,
-                                        jnp.reshape(lam_j, (1,)), f_ce
-                                    )
+                                beta_b, resid_b = kops.bcd_epochs_fused(
+                                    Xt_full, Lg, problem.w, fmask[None],
+                                    beta[None], resid_nc[None],
+                                    problem.tau,
+                                    jnp.reshape(lam_j, (1,)), f_ce
+                                )
                                 beta, resid_nc = beta_b[0], resid_b[0]
                                 self.fused_epoch_launches += 1
                             except KernelLaunchError:
@@ -1121,16 +1133,13 @@ class SGLSession:
                         with obs_trace.span("epoch_block"):
                             try:
                                 _fire_epoch_launch_fault()
-                                with _launch_span("pallas"):
-                                    beta_b, z_b = (
-                                        kops.bcd_epochs_logistic_fused(
-                                            Xt_full, Lg, problem.w,
-                                            fmask[None], beta[None],
-                                            z_nc[None], problem.y,
-                                            problem.tau,
-                                            jnp.reshape(lam_j, (1,)), f_ce
-                                        )
-                                    )
+                                beta_b, z_b = kops.bcd_epochs_logistic_fused(
+                                    Xt_full, Lg, problem.w,
+                                    fmask[None], beta[None],
+                                    z_nc[None], problem.y,
+                                    problem.tau,
+                                    jnp.reshape(lam_j, (1,)), f_ce
+                                )
                                 beta, z_nc = beta_b[0], z_b[0]
                                 self.fused_epoch_launches += 1
                             except KernelLaunchError:
@@ -1326,7 +1335,7 @@ class SGLSession:
             # path is per-lambda): a failed fused launch surfaces as the
             # KernelLaunchError it raised instead of a silent retry.
             _fire_epoch_launch_fault()
-            with obs_trace.span("epoch_block"), _launch_span("pallas"):
+            with obs_trace.span("epoch_block"):
                 bsub, resid = kops.bcd_epochs_fused(
                     Xt, Lg_eff, w, fm_b, bsub, resid, problem.tau,
                     lam_b, block
@@ -1335,10 +1344,12 @@ class SGLSession:
             step += block
             if self.budget is not None:
                 self.budget.note_epochs(block * B)
-            red = np.asarray(_batch_reduced_gaps(
+            red = _batch_reduced_gaps(
                 Xt, fm_b, bsub, resid, w, y, problem.tau, lam_b,
                 backend=self.solver_backend, xt_rows=xt_rows,
-            ))
+            )
+            with _read("reduced_gaps"):
+                red = np.asarray(red)
             changed = False
             for b in range(B):
                 if done[b]:
@@ -1381,8 +1392,11 @@ class SGLSession:
                     rres = self._compact_round(
                         beta_full, lam_b[b], base_g, f_act[b], caches
                     )
-                    if rres is not None and float(rres.gap) <= tol:
-                        rres = None        # full-round confirmation below
+                    if rres is not None:
+                        with _read("gap"):
+                            compact_done = float(rres.gap) <= tol
+                        if compact_done:
+                            rres = None    # full-round confirmation below
                 if rres is None:
                     rres = self._certified_round(
                         beta_full, lam_b[b], lam_max_j, self.rule,
@@ -1411,8 +1425,9 @@ class SGLSession:
                     # before re-confirming this lambda.
                     hold_b[b] = step + f_ce
                 n_g0, n_f0 = g_act[b].sum(), f_act[b].sum()
-                g_act[b] &= np.asarray(rres.group_active)
-                f_act[b] &= np.asarray(rres.feat_active)
+                with _read("masks"):
+                    g_act[b] &= np.asarray(rres.group_active)
+                    f_act[b] &= np.asarray(rres.feat_active)
                 f_act[b] &= g_act[b][:, None]
                 if g_act[b].sum() != n_g0 or f_act[b].sum() != n_f0:
                     changed = True
@@ -1503,8 +1518,10 @@ class SGLSession:
 
         G, ng = problem.G, problem.ng
         dtype = problem.X.dtype
-        n_feat = int(np.asarray(problem.feat_mask).sum())
-        n_groups = int(np.asarray(jnp.any(problem.feat_mask, axis=-1)).sum())
+        with _read("problem"):
+            n_feat = int(np.asarray(problem.feat_mask).sum())
+            n_groups = int(np.asarray(
+                jnp.any(problem.feat_mask, axis=-1)).sum())
         rounds0 = self.rounds
         compact0 = self.compact_rounds
         full0 = self.full_rounds
@@ -1538,11 +1555,12 @@ class SGLSession:
         def record(t, res, first_round, n_seq_active):
             """Per-lambda bookkeeping shared by the per-lambda and the
             batched-lambda drivers (mutates the dense path arrays)."""
-            betas[t] = np.asarray(res.beta)
-            gaps[t] = float(res.gap)
+            with _read("result"):
+                betas[t] = np.asarray(res.beta)
+                gaps[t] = float(res.gap)
+                g_act[t] = np.asarray(res.group_active)
+                f_act[t] = np.asarray(res.feat_active)
             epochs[t] = res.n_epochs
-            g_act[t] = np.asarray(res.group_active)
-            f_act[t] = np.asarray(res.feat_active)
             if first_round is not None and screening_rule:
                 if np.dtype(dtype).itemsize >= 8:
                     # Report the sequential certificate even when solve
@@ -1638,9 +1656,10 @@ class SGLSession:
                     if not np.isfinite(float(first_round.gap)):
                         first_round = None
                 if first_round is not None and screening_rule:
-                    n_seq_active = int(
-                        np.asarray(first_round.group_active).sum()
-                    )
+                    with _read("masks"):
+                        n_seq_active = int(
+                            np.asarray(first_round.group_active).sum()
+                        )
                     seq_scr[t] = n_groups - n_seq_active
 
             warm_here = (first_round is not None
@@ -1675,7 +1694,8 @@ class SGLSession:
                         # the batched driver's adopted masks; stop probing
                         # — lambda k re-certifies later from a warmer beta.
                         break
-                    cg = np.asarray(ck.group_active)
+                    with _read("masks"):
+                        cg = np.asarray(ck.group_active)
                     if (_bucket(max(int((union_g | cg).sum()), 1))
                             <= 2 * bucket0):
                         union_g |= cg

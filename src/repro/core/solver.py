@@ -95,6 +95,7 @@ from ..kernels import _util as kernel_util
 from ..kernels import ops as kops
 from ..losses import Loss, resolve_loss
 from ..obs import metrics as obs_metrics
+from ..obs import trace as obs_trace
 from ..rules import RuleState, ScreeningRule, resolve_rule
 
 _M_GATHERS = obs_metrics.REGISTRY.counter(
@@ -210,7 +211,8 @@ class SolveCaches:
         self._sync_problem(problem)
         key = group_active.tobytes()
         if key != self.gather_key:
-            self.gather_val = _gather_static(problem, group_active)
+            with obs_trace.span("gather"):
+                self.gather_val = _gather_static(problem, group_active)
             self.gather_key = key
             self.n_gathers += 1
             _M_GATHERS.inc()
@@ -225,9 +227,10 @@ class SolveCaches:
         key = group_active.tobytes()
         if key != self.xt_rows_key:
             _, take, *_ = self.gather(problem, group_active)
-            self.xt_rows_val = kops.gather_transposed_rows(
-                xt_pre, take, problem.ng
-            )
+            with obs_trace.span("gather"):
+                self.xt_rows_val = kops.gather_transposed_rows(
+                    xt_pre, take, problem.ng
+                )
             self.xt_rows_key = key
         return self.xt_rows_val
 

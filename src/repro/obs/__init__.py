@@ -16,12 +16,15 @@ Pieces
 
 :mod:`repro.obs.trace`
     Structured tracing: nested spans ``serve.request → serve.coalesce →
-    path → lambda → round → epoch_block → kernel_launch`` with an
+    path → lambda → round / epoch_block / read / masks / gather`` with an
     injectable monotonic clock, a bounded ring buffer, JSONL export and
     percentile aggregation.  Span *recording* is sampled; per-site fire
-    counters are always exact.  The whole layer is OFF by default, and the
-    disabled path allocates no span objects and takes no lock — hot solver
-    loops see a single module-global read returning a no-op singleton.
+    counters are always exact.  While a JAX profiler session records, the
+    same spans are also written to the profiler trace as ``repro.<name>``
+    annotations, on the device trace's clock.  Both outputs are OFF by
+    default, and then the span sites allocate nothing and take no lock —
+    hot solver loops see a global read and one ``is_enabled()`` call
+    returning a no-op singleton.
 
 :mod:`repro.obs.timing`
     Measured kernel timing: a jit-warm + ``block_until_ready`` harness
@@ -40,12 +43,15 @@ Pieces
 
 Enabling
 --------
-Tracing is opt-in per process::
+The ring buffer is opt-in per process::
 
     from repro.obs import trace
     trace.configure(enabled=True)        # or REPRO_OBS=1 in the env
     ... run ...
     trace.TRACER.export_jsonl("spans.jsonl")
+
+The profiler output needs no configuration: any ``jax.profiler`` session
+(``start_trace``/``stop_trace``, TensorBoard's capture) turns it on.
 
 Metrics counters are always live (they are just locked ints — the
 pre-obs code paths already paid for plain module globals / dict writes).
@@ -54,7 +60,7 @@ from __future__ import annotations
 
 import os
 
-from . import metrics, trace  # noqa: F401  (stdlib-only leaf modules)
+from . import metrics, trace  # noqa: F401  (leaf modules)
 
 if os.environ.get("REPRO_OBS", "") not in ("", "0"):  # pragma: no cover
     trace.configure(enabled=True)

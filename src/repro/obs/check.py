@@ -16,7 +16,7 @@ static-analysis gate, so CI treats both gates identically):
   :data:`repro.obs.trace.SPAN_SITES` must actually fire on a smoke
   path: a tiny two-request serve sequence (pallas backends, warm-start
   second request) that traverses request → coalesce → store → cache →
-  warm_eval → path → lambda → round → epoch_block → kernel_launch.  A
+  warm_eval → path → lambda → round → epoch_block, read, masks, gather.  A
   site that never fires means its instrumentation was dropped in a
   refactor — exactly the regression this gate exists to catch.  The
   tracer's *exact* per-site counters are used (sampling only thins the

@@ -1,4 +1,5 @@
-"""Structured tracing: nested spans, ring buffer, JSONL, percentiles.
+"""Structured tracing: nested spans with two outputs, a ring buffer and
+the profiler trace.
 
 Span taxonomy (declared in :data:`SPAN_SITES`, audited by OB002)::
 
@@ -11,26 +12,39 @@ Span taxonomy (declared in :data:`SPAN_SITES`, audited by OB002)::
         lambda               one path point
           round              one certified GAP round (full or compact)
           epoch_block        one BCD epoch-block dispatch
-            kernel_launch    one fused Pallas launch (host-side dispatch)
+          read               one blocking device->host read (``what``)
+          masks              NumPy mask adoption and beta masking
+          gather             a rebuild of the compacted active buffer
 
 Contract
 --------
-* **Off by default, zero-overhead when off.**  ``span(name)`` with tracing
-  disabled is one module-global read returning the preallocated
-  :data:`NOOP` singleton — no ``Span`` allocation, no lock.  The hot solver
-  loops rely on this; ``tests/test_obs.py`` asserts the allocation count
-  stays flat across a full solve.
-* **Counters exact, recording sampled.**  While enabled, every ``span()``
-  call bumps the per-site fire counter exactly; only every
-  ``sample_every``-th *root* span (and its whole subtree) is recorded into
-  the bounded ring buffer.  Percentiles therefore come from a sample;
-  counts never do.
+* **Two outputs, one API.**  ``span(name)`` records into the ring buffer
+  while ``configure(enabled=True)`` is set, and, independently, while a
+  JAX profiler session records (``jax.profiler.TraceAnnotation
+  .is_enabled()``: the benchmark's, an operator's, TensorBoard's) it also
+  opens a ``TraceAnnotation`` named ``repro.<name>``.  That event lands
+  on the profiler's host plane, beside the device's program executions;
+  attributes set with ``.set()`` become its metadata.
+* **Off by default, zero-overhead when off.**  With neither output on,
+  ``span(name)`` is one module-global read and one ``is_enabled()`` call
+  returning the preallocated :data:`NOOP` singleton: no ``Span``, no
+  annotation, no lock.  The hot solver loops rely on this;
+  ``tests/test_obs.py`` asserts the allocation count stays flat across a
+  full solve.
+* **Counters exact, recording sampled.**  While the ring buffer is
+  enabled, every ``span()`` call bumps the per-site fire counter exactly
+  (:meth:`Tracer.counts`); only every ``sample_every``-th *root* span
+  (and its whole subtree) is recorded into the bounded ring buffer.
+  Percentiles therefore come from a sample; counts never do.  Spans
+  written to the profiler are counted apart, exactly
+  (:meth:`Tracer.profiler_counts`).
 * **Injectable clock.**  ``configure(clock=...)`` takes any monotonic
   ``() -> float``; tests drive a fake clock to get deterministic
   histograms.
-* Span timings taken around jitted calls measure the *host-side dispatch
-  window* (JAX is asynchronous); measured kernel wall-clock truth comes
-  from :mod:`repro.obs.timing`'s ``block_until_ready`` harness.
+* Ring-buffer timings taken around jitted calls measure the *host-side
+  dispatch window* (JAX is asynchronous).  The profiler output puts the
+  same windows beside the device's program executions, so device idle
+  time can be labelled with the host work that caused it.
 """
 from __future__ import annotations
 
@@ -39,6 +53,12 @@ import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
+#: Name prefix of the spans written to the profiler trace.
+PROFILER_PREFIX = "repro."
+_profiling = _TraceAnnotation.is_enabled
 
 #: Declared span sites: name -> where it fires.  ``repro.obs --check``
 #: (OB002) runs a smoke path and fails if any of these never fired.
@@ -52,21 +72,28 @@ SPAN_SITES: Dict[str, str] = {
     "lambda": "core/session.py:solve_path — one path point",
     "round": "core/session.py — one certified GAP round (full or compact)",
     "epoch_block": "core/session.py:solve — one BCD epoch-block dispatch",
-    "kernel_launch": "core/session.py — fused Pallas launch dispatch",
+    "read": "core/session.py — one blocking device->host read of the "
+            "solve loop (metadata what=)",
+    "masks": "core/session.py:solve — NumPy mask adoption + beta masking",
+    "gather": "core/solver.py:SolveCaches — active-buffer rebuild",
 }
 
 
 class Span:
-    """A recorded span.  Only ever allocated while tracing is enabled."""
+    """A live span.  Only ever allocated while an output is on: the ring
+    buffer (``record``) or a profiler session."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t_start",
-                 "t_end", "attrs", "sampled", "_tracer")
+                 "t_end", "attrs", "sampled", "record", "_tracer",
+                 "_annotation")
 
     _allocated = 0  # class-level tally; GIL-atomic += is fine for the assert
 
-    def __init__(self, tracer: "Tracer", name: str):
+    def __init__(self, tracer: "Tracer", name: str, record: bool = True):
         Span._allocated += 1
         self._tracer = tracer
+        self.record = record
+        self._annotation = None
         self.name = name
         self.trace_id = -1
         self.span_id = -1
@@ -84,6 +111,8 @@ class Span:
         if self.attrs is None:
             self.attrs = {}
         self.attrs[key] = value
+        if self._annotation is not None:
+            self._annotation.set_metadata(**{key: value})
         return self
 
     def __enter__(self) -> "Span":
@@ -127,6 +156,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._counts: Dict[str, int] = {}
+        self._profiled: Dict[str, int] = {}
         self._root_seq = 0
         self._span_seq = 0
         self._open = 0
@@ -154,15 +184,18 @@ class Tracer:
         with self._lock:
             self._buffer.clear()
             self._counts = {}
+            self._profiled = {}
             self._root_seq = 0
             self._span_seq = 0
 
     # -- span machinery --------------------------------------------------
     def span(self, name: str):
-        """The one hot-path entry point.  Disabled → NOOP singleton."""
-        if not self._enabled:
-            return NOOP
-        return Span(self, name)
+        """The one hot-path entry point.  No output on → NOOP singleton."""
+        if self._enabled:
+            return Span(self, name)
+        if _profiling():
+            return Span(self, name, record=False)
+        return NOOP
 
     def _stack(self) -> List[Span]:
         st = getattr(self._local, "stack", None)
@@ -171,6 +204,14 @@ class Tracer:
         return st
 
     def _enter(self, sp: Span) -> None:
+        if _profiling():
+            sp._annotation = _TraceAnnotation(PROFILER_PREFIX + sp.name,
+                                              **(sp.attrs or {}))
+            sp._annotation.__enter__()
+            with self._lock:
+                self._profiled[sp.name] = self._profiled.get(sp.name, 0) + 1
+        if not sp.record:
+            return
         st = self._stack()
         with self._lock:
             self._counts[sp.name] = self._counts.get(sp.name, 0) + 1
@@ -190,6 +231,13 @@ class Tracer:
         sp.t_start = self._clock()
 
     def _exit(self, sp: Span) -> None:
+        if sp.record:
+            self._record(sp)
+        if sp._annotation is not None:
+            sp._annotation.__exit__(None, None, None)
+            sp._annotation = None
+
+    def _record(self, sp: Span) -> None:
         sp.t_end = self._clock()
         st = self._stack()
         if st and st[-1] is sp:
@@ -212,6 +260,12 @@ class Tracer:
         """Exact per-site fire counts since the last reset()."""
         with self._lock:
             return dict(self._counts)
+
+    def profiler_counts(self) -> Dict[str, int]:
+        """Exact per-site counts of the spans written to the profiler
+        trace since the last reset()."""
+        with self._lock:
+            return dict(self._profiled)
 
     def open_spans(self) -> int:
         return self._open
@@ -263,13 +317,15 @@ TRACER = Tracer()
 
 
 def span(name: str):
-    """Open a span on the global tracer.  With tracing disabled this is a
-    single global read returning the :data:`NOOP` singleton — no
-    allocation, no lock."""
+    """Open a span on the global tracer.  With neither output on this is
+    a global read and an ``is_enabled()`` call returning the :data:`NOOP`
+    singleton — no allocation, no lock."""
     t = TRACER
-    if not t._enabled:
-        return NOOP
-    return Span(t, name)
+    if t._enabled:
+        return Span(t, name)
+    if _profiling():
+        return Span(t, name, record=False)
+    return NOOP
 
 
 def configure(enabled: Optional[bool] = None,

@@ -2,9 +2,13 @@
 
 The contracts defended here, in the order they matter:
 
-* **zero overhead off** — with tracing disabled, ``span()`` returns the
-  preallocated NOOP singleton and allocates nothing, and running a full
-  solve with tracing ON is bit-identical to OFF;
+* **zero overhead off** — with neither output on (ring buffer, profiler
+  session), ``span()`` returns the preallocated NOOP singleton and
+  allocates no span and no annotation, and running a full solve with
+  either output ON is bit-identical to OFF;
+* **the profiler output** — under ``jax.profiler.start_trace`` the solve's
+  spans land on the host plane as ``repro.<site>`` events, the same
+  number on every identical solve;
 * **scope parity** — ``MetricsRegistry.scope`` keeps the exact
   ``kernels.ops.audit_scope()`` semantics (zero on entry, live deltas,
   freeze on exit, outer values restored, nothing propagated);
@@ -16,6 +20,7 @@ The contracts defended here, in the order they matter:
 * **the gate finds things** — OB001/OB002 findings fire on seeded bad
   fixtures, and the live schema/snapshot pass clean.
 """
+import collections
 import json
 import threading
 
@@ -239,14 +244,22 @@ def test_faults_fired_counter():
 # tracing: disabled fast path, fake clock, sampling, threads
 # ---------------------------------------------------------------------------
 
-def test_disabled_span_is_noop_and_allocation_free():
+def test_disabled_span_is_noop_and_allocation_free(monkeypatch):
     assert not ot.TRACER.enabled
+    annotations = []
+    monkeypatch.setattr(ot, "_TraceAnnotation",
+                        lambda *a, **k: annotations.append(a))
     before = ot.Span.allocated()
     for _ in range(100):
         with ot.span("round") as sp:
             sp.set("k", 1)
+        with ot.span("read").set("what", "gap"):
+            pass
     assert ot.span("path") is ot.NOOP
+    assert ot.TRACER.span("path") is ot.NOOP
     assert ot.Span.allocated() == before
+    assert annotations == []
+    assert ot.TRACER.profiler_counts() == {}
 
 
 def _fake_clock(step=0.25):
@@ -337,19 +350,41 @@ def small_problem():
     return sgl.make_problem(X, y, sizes, tau=0.3)
 
 
-def test_traced_solve_bit_identical(small_problem):
+def _small_path(problem):
     from repro.core.session import SGLSession, SolverConfig
 
-    cfg = dict(tol=1e-6, max_epochs=2000)
+    return SGLSession(problem, SolverConfig(tol=1e-6, max_epochs=2000)
+                      ).solve_path(T=3, delta=1.5)
+
+
+def _profiled(fn, tmp_path):
+    """Run ``fn`` under a profiler session; return its result and the
+    ``repro.*`` events of the host plane as (name, metadata) pairs."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    [xplane] = list(tmp_path.glob("**/*.xplane.pb"))
+    data = ProfileData.from_file(str(xplane))
+    events = [(ev.name, dict(ev.stats))
+              for plane in data.planes if plane.name == "/host:CPU"
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith(ot.PROFILER_PREFIX)]
+    return out, events
+
+
+def test_traced_solve_bit_identical(small_problem, tmp_path):
     before = ot.Span.allocated()
-    off = SGLSession(small_problem,
-                     SolverConfig(**cfg)).solve_path(T=3, delta=1.5)
+    off = _small_path(small_problem)
     assert ot.Span.allocated() == before          # hot path allocated nothing
     ot.configure(enabled=True, sample_every=1)
     ot.TRACER.reset()
     try:
-        on = SGLSession(small_problem,
-                        SolverConfig(**cfg)).solve_path(T=3, delta=1.5)
+        on = _small_path(small_problem)
         counts = ot.TRACER.counts()
     finally:
         ot.configure(enabled=False)
@@ -358,6 +393,47 @@ def test_traced_solve_bit_identical(small_problem):
     assert counts["path"] == 1 and counts["lambda"] == 3
     assert counts["round"] > 0 and counts["epoch_block"] > 0
     assert ot.TRACER.open_spans() == 0
+    # the profiler output changes nothing either
+    profiled, events = _profiled(lambda: _small_path(small_problem),
+                                 tmp_path)
+    assert events
+    np.testing.assert_array_equal(np.asarray(profiled.betas),
+                                  np.asarray(off.betas))
+
+
+def test_profiled_solve_writes_repro_spans_to_the_host_plane(
+        small_problem, tmp_path):
+    """Under a profiler session the solve's spans are ``repro.<site>``
+    events on ``/host:CPU``, with their attributes as metadata; with the
+    ring buffer on too, both outputs count every span."""
+    ot.configure(enabled=True, sample_every=1)
+    ot.TRACER.reset()
+    try:
+        _, events = _profiled(lambda: _small_path(small_problem), tmp_path)
+        counts = ot.TRACER.counts()
+        profiled = ot.TRACER.profiler_counts()
+    finally:
+        ot.configure(enabled=False)
+        ot.TRACER.reset()
+    names = collections.Counter(name for name, _ in events)
+    for site in ("path", "lambda", "round", "epoch_block", "read", "masks"):
+        assert names["repro." + site] > 0, site
+    assert names["repro.path"] == 1 and names["repro.lambda"] == 3
+    assert {k: names["repro." + k] for k in counts} == counts == profiled
+    reads = [meta for name, meta in events if name == "repro.read"]
+    assert all(meta.get("what") for meta in reads)
+    assert {"gap", "masks", "k_done", "result"} <= {m["what"] for m in reads}
+    rounds = [meta for name, meta in events if name == "repro.round"]
+    assert all("compact" in meta for meta in rounds)
+
+
+def test_profiled_solves_count_the_same_reads(small_problem, tmp_path):
+    reads = []
+    for k in range(2):
+        _, events = _profiled(lambda: _small_path(small_problem),
+                              tmp_path / str(k))
+        reads.append(sum(name == "repro.read" for name, _ in events))
+    assert reads[0] == reads[1] > 0
 
 
 def test_serve_worker_traced_under_chaos(small_problem):
@@ -423,6 +499,8 @@ def test_ob001_clean_on_live_schema():
 
 
 def test_ob002_fires_on_missing_and_undeclared_sites():
+    assert "kernel_launch" not in ot.SPAN_SITES
+    assert {"read", "masks", "gather"} <= set(ot.SPAN_SITES)
     full = {site: 1 for site in ot.SPAN_SITES}
     assert ocheck.check_span_coverage(full) == []
     missing = dict(full)
@@ -504,6 +582,10 @@ def test_render_obs_markdown_smoke():
     assert "`serve.request`" in md
     assert "+1.00%" in md
     assert "'failed'" not in md                    # zero counters dropped
+
+
+def test_ob002_smoke_fires_every_declared_site():
+    assert ocheck.check_span_coverage() == []
 
 
 def test_obs_check_payload_schema():
