@@ -135,8 +135,18 @@ from ..faults.errors import KernelLaunchError, NumericsError
 from ..faults.inject import fire as _fire_fault
 from ..kernels import ops as kops
 from ..losses import Loss, resolve_loss
+from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from ..rules import ScreeningRule, resolve_rule
+
+
+_M_GROUP_STEPS = obs_metrics.REGISTRY.counter(
+    "solver.epoch_group_steps",
+    help="Group steps run by the compacted epoch blocks (_inner_rounds)")
+_M_GROUP_SLOTS = obs_metrics.REGISTRY.counter(
+    "solver.epoch_group_slots",
+    help="Buffer slots spanned by those epochs (Gb x epochs); the share "
+         "not stepped is bucket padding the XLA scan skipped")
 
 
 def _read(what: str):
@@ -322,6 +332,15 @@ class PathResult(NamedTuple):
                                    #   consecutive lambdas whose sequential
                                    #   certificates agreed on the active
                                    #   groups.  0 when no batching engaged.
+    n_group_steps: int = 0         # group steps run by the compacted
+                                   #   epoch blocks (_inner_rounds); 0
+                                   #   where epochs run elsewhere
+                                   #   (compact=False, a batched-lambda
+                                   #   run, the mesh strategy)
+    n_group_slots: int = 0         # buffer slots those epochs spanned
+                                   #   (Gb x epochs); the XLA scan skips
+                                   #   the padding past the last live
+                                   #   chunk, so steps <= slots
     rule_name: str = "gap"         # registered name of the screening rule
                                    #   that produced this path
     certificates_safe: bool = True # the group/feat_active masks are safe
@@ -502,6 +521,11 @@ class SGLSession:
         # Epoch blocks dispatched as ONE fused Pallas launch instead of an
         # O(G) lax.scan (solver_backend="pallas" only).
         self.fused_epoch_launches = 0
+        # Group steps the compacted epoch blocks ran, and the buffer slots
+        # they spanned (Gb per epoch): the XLA scan skips the slots past
+        # the last live chunk.
+        self.epoch_group_steps = 0
+        self.epoch_group_slots = 0
         # Fault-tolerance accounting + per-request budget (repro.faults):
         # certified rounds discarded for a non-finite gap (the solve loop
         # rewinds and re-runs them), pallas→reference kernel demotions
@@ -1073,17 +1097,23 @@ class SGLSession:
 
                 with obs_trace.span("epoch_block"):
                     try:
-                        beta, k_done, _ = _epochs_compact(
+                        beta, k_done, _, steps = _epochs_compact(
                             self.solver_backend, xt_rows
                         )
                     except KernelLaunchError:
                         if self.solver_backend != "pallas":
                             raise
                         self._demote_solver_backend()
-                        beta, k_done, _ = _epochs_compact("xla", None)
+                        beta, k_done, _, steps = _epochs_compact(
+                            "xla", None)
                 with _read("k_done"):
-                    k_done = int(k_done)
+                    k_done, steps = (int(v) for v in
+                                     jax.device_get((k_done, steps)))
                 epochs_done += check * k_done
+                self.epoch_group_steps += steps
+                self.epoch_group_slots += Xt.shape[0] * check * k_done
+                _M_GROUP_STEPS.inc(steps)
+                _M_GROUP_SLOTS.inc(Xt.shape[0] * check * k_done)
                 if self.solver_backend == "pallas" and (
                         lsq or self.loss.name == "logistic"):
                     # Each inner block ran as ONE fused kernel launch
@@ -1527,6 +1557,8 @@ class SGLSession:
         full0 = self.full_rounds
         flops0 = self.round_flops
         fused0 = self.fused_epoch_launches
+        steps0 = self.epoch_group_steps
+        slots0 = self.epoch_group_slots
         batched0 = self.batched_lambdas
         traces0 = kops.transpose_trace_count()
 
@@ -1801,6 +1833,8 @@ class SGLSession:
             round_flops=self.round_flops - flops0,
             n_fused_epoch_launches=self.fused_epoch_launches - fused0,
             batched_lambdas=self.batched_lambdas - batched0,
+            n_group_steps=self.epoch_group_steps - steps0,
+            n_group_slots=self.epoch_group_slots - slots0,
             rule_name=rule.name,
             certificates_safe=rule.is_safe,
             degraded=path_degraded,

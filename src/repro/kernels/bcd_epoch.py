@@ -48,7 +48,9 @@ guards), so interpret-mode f64 results are bit-identical to the
 ``lax.scan`` reference — asserted by ``tests/test_bcd_kernel.py``.  Masked
 and bucket-padded groups ride along with ``Lg <= 0`` and a zero feature
 mask: their coefficients are left untouched and their residual delta is an
-exact zero, so duplicate-alias ``take`` slots are inert.
+exact zero, so duplicate-alias ``take`` slots are inert.  (The reference
+does not visit the slots past the chunk holding the last live group; this
+kernel steps through them as those exact no-ops, so the results agree.)
 
 On CPU this executes with ``interpret=True`` (bit-parity reference mode); on
 TPU the same code lowers to Mosaic.  TPU tiling note: ``ng`` rides the lane

@@ -34,7 +34,9 @@ def bcd_epochs_ref(Xt, Lg, w, fmask, beta, resid, tau, lam_b, n_epochs):
     The per-group update is line-for-line
     :func:`repro.core.solver.bcd_epochs` (the solver's XLA path), applied
     independently per lambda b — the fused kernel must match this
-    BIT-exactly in f64 interpret mode.  ``Xt (Gb, n, ng)``, ``Lg``/``w``
+    BIT-exactly in f64 interpret mode.  This oracle scans every slot;
+    ``bcd_epochs`` does not visit the slots past the chunk holding the
+    last live group, whose updates are exact no-ops.  ``Xt (Gb, n, ng)``, ``Lg``/``w``
     ``(Gb,)``, ``fmask``/``beta`` ``(B, Gb, ng)``, ``resid (B, n)``,
     ``lam_b (B,)``.
     """
@@ -82,7 +84,8 @@ def bcd_epochs_logistic_ref(Xt, Lg, w, fmask, beta, z, y, tau, lam_b,
     (majorization bound ``Lg / 4``, fresh ``rho = y - sigmoid(z)`` per
     group, rank-one linear-predictor update), applied independently per
     lambda — the fused logistic kernel must match BIT-exactly in f64
-    interpret mode.  ``z (B, n)`` is the linear predictor carry.
+    interpret mode.  This oracle scans every slot; ``bcd_epochs_loss``
+    does not visit the slots past the chunk holding the last live group.  ``z (B, n)`` is the linear predictor carry.
     """
     live = (Lg > 0).astype(beta.dtype)
     Lmaj = 0.25 * Lg

@@ -640,7 +640,8 @@ _ARRAY_FIELDS = ("betas", "gaps", "epochs", "group_active_frac",
                  "seq_screened", "dyn_screened")
 _SUM_FIELDS = ("n_rounds", "n_transpose_copies", "n_compact_rounds",
                "n_full_rounds", "round_flops", "n_fused_epoch_launches",
-               "batched_lambdas", "n_gathers")
+               "batched_lambdas", "n_gathers", "n_group_steps",
+               "n_group_slots")
 
 
 def _pack_state(acc, segments: List[PathResult], beta_carry) -> dict:
@@ -651,7 +652,8 @@ def _pack_state(acc, segments: List[PathResult], beta_carry) -> dict:
             + [np.asarray(getattr(s, f)) for s in segments]
         state[f] = np.concatenate(parts, axis=0)
     for f in _SUM_FIELDS:
-        prior = float(acc[f]) if acc is not None else 0.0
+        # A checkpoint written before a counter existed restores without it.
+        prior = float(acc[f]) if acc is not None and f in acc else 0.0
         state[f] = np.asarray(
             prior + sum(float(getattr(s, f)) for s in segments))
     safe_prior = bool(acc["certificates_safe"]) if acc is not None else True
@@ -696,6 +698,8 @@ def _assemble(lambdas: np.ndarray, acc,
         round_flops=counters["round_flops"],
         n_fused_epoch_launches=counters["n_fused_epoch_launches"],
         batched_lambdas=counters["batched_lambdas"],
+        n_group_steps=counters["n_group_steps"],
+        n_group_slots=counters["n_group_slots"],
         rule_name=rule_name,
         certificates_safe=bool(state["certificates_safe"]),
         degraded=degraded,
@@ -727,6 +731,8 @@ def _slice_result(result: PathResult, idx: np.ndarray) -> PathResult:
         round_flops=result.round_flops,
         n_fused_epoch_launches=result.n_fused_epoch_launches,
         batched_lambdas=result.batched_lambdas,
+        n_group_steps=result.n_group_steps,
+        n_group_slots=result.n_group_slots,
         rule_name=result.rule_name,
         certificates_safe=result.certificates_safe,
         degraded=result.degraded,

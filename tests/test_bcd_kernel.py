@@ -1,15 +1,21 @@
 """Fused BCD-epoch mega-kernel: interpret-mode bit-parity vs the lax.scan
 reference, batched-lambda grid semantics, and the session-level pin that
 ``solver_backend="pallas"`` reproduces the XLA path exactly."""
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
 from repro.core import sgl
 from repro.core.session import SGLSession, SolverConfig
-from repro.core.solver import bcd_epochs, resolve_solver_backend
+from repro.core.solver import (
+    bcd_epochs,
+    bcd_epochs_loss,
+    resolve_solver_backend,
+)
 from repro.data.synthetic import make_synthetic
 from repro.kernels import ops, ref
+from repro.losses import resolve_loss
 
 
 def _gathered_like(rng, Gb, n, ng, B=1, dead_frac=0.3, dup_alias=True):
@@ -85,6 +91,101 @@ def test_fused_epochs_batched_grid_equals_per_lambda(rng):
                                       np.asarray(want_b))
         np.testing.assert_array_equal(np.asarray(got_r[b]),
                                       np.asarray(want_r))
+
+
+def _loss_epochs_full_scan(Xt, Lg, w, fm, beta, z, y, tau, lam_, loss,
+                           n_epochs):
+    """``bcd_epochs_loss`` as a scan over every slot: the oracle for the
+    lsq loss (``ref.bcd_epochs_logistic_ref`` is the logistic one)."""
+    live = (Lg > 0).astype(beta.dtype)
+    safe_L = jnp.where(Lg > 0, loss.nu * Lg, 1.0)
+    step = lam_ / safe_L
+    thr1 = tau * step
+    thr2 = (1.0 - tau) * w * step
+
+    def group_update(z, inputs):
+        Xg, bg, L, t1, t2, m, lv = inputs
+        grad_step = (Xg.T @ loss.neg_grad(y, z)) / L
+        u = (bg + grad_step) * m
+        u = jnp.sign(u) * jnp.maximum(jnp.abs(u) - t1, 0.0)
+        nrm = jnp.linalg.norm(u)
+        u = jnp.maximum(1.0 - t2 / jnp.maximum(nrm, 1e-30), 0.0) * u
+        new_bg = jnp.where(lv > 0, u, bg)
+        return z + Xg @ (new_bg - bg), new_bg
+
+    def epoch(carry, _):
+        beta, z = carry
+        z, beta = jax.lax.scan(group_update, z,
+                               (Xt, beta, safe_L, thr1, thr2, fm, live))
+        return (beta, z), None
+
+    (beta, z), _ = jax.lax.scan(epoch, (beta, z), None, length=n_epochs)
+    return beta, z
+
+
+def _live_pattern(rng, Gb, pattern):
+    if pattern == "all":
+        return np.ones(Gb, bool)
+    if pattern == "none":
+        return np.zeros(Gb, bool)
+    if pattern == "scattered":       # the uncompacted solve's dead groups
+        live = rng.random(Gb) < 0.5
+        live[[1, -2]] = True, False
+        return live
+    return np.arange(Gb) < pattern   # a live prefix, as _gather_static makes
+
+
+_LIVE_CASES = ([(8, k) for k in ("all", 1, 7, "scattered", "none")]
+               + [(64, k) for k in ("all", 1, 7, 8, 9, 63, "scattered",
+                                    "none")]
+               + [(10, "scattered"), (15, "scattered")])
+
+
+@pytest.mark.parametrize("epochs", ["lsq_resid", "lsq", "logistic"])
+@pytest.mark.parametrize("Gb,pattern", _LIVE_CASES)
+def test_live_bounded_epochs_bit_identical_to_full_scan(Gb, pattern, epochs,
+                                                        rng):
+    """The group loop stops after the chunk holding the last live slot;
+    beta and the carry match a scan over every slot bit for bit, and with
+    no live slot they come back unchanged."""
+    n, ng = 17, 5
+    live = _live_pattern(rng, Gb, pattern)
+    Xt = rng.standard_normal((Gb, n, ng))
+    Lg = np.where(live, rng.uniform(0.5, 3.0, Gb), 0.0)
+    fm = (rng.random((Gb, ng)) < 0.85) * live[:, None].astype(float)
+    w = np.sqrt(ng) * np.ones(Gb)
+    beta = rng.standard_normal((Gb, ng)) * fm
+    carry = rng.standard_normal(n)
+    y = (rng.random(n) < 0.5).astype(float)
+    Xt, Lg, w, fm, y = map(jnp.asarray, (Xt, Lg, w, fm, y))
+    tau, lam = jnp.asarray(0.3), jnp.asarray(0.45)
+
+    def args():                      # fresh buffers: beta/carry are donated
+        return jnp.asarray(beta), jnp.asarray(carry)
+
+    if epochs == "lsq_resid":
+        got = bcd_epochs(Xt, Lg, w, fm, *args(), tau, lam, 3)
+        want = ref.bcd_epochs_ref(Xt, Lg, w, fm[None],
+                                  *(a[None] for a in args()), tau,
+                                  jnp.reshape(lam, (1,)), 3)
+        want = want[0][0], want[1][0]
+    else:
+        loss = resolve_loss(epochs)
+        got = bcd_epochs_loss(Xt, Lg, w, fm, *args(), tau, lam, y, loss, 3)
+        if epochs == "logistic":
+            b0, z0 = args()
+            want = ref.bcd_epochs_logistic_ref(Xt, Lg, w, fm[None], b0[None],
+                                               z0[None], y, tau,
+                                               jnp.reshape(lam, (1,)), 3)
+            want = want[0][0], want[1][0]
+        else:
+            want = _loss_epochs_full_scan(Xt, Lg, w, fm, *args(), y, tau,
+                                          lam, loss, 3)
+    for g, wnt in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(wnt))
+    if pattern == "none":
+        np.testing.assert_array_equal(np.asarray(got[0]), beta)
+        np.testing.assert_array_equal(np.asarray(got[1]), carry)
 
 
 def test_fused_epochs_zero_epochs_is_identity(rng):

@@ -116,3 +116,58 @@ def test_solve_path_pallas_backend_end_to_end(prob):
     res = solve_path(prob, T=4, delta=1.5, tol=1e-7, rule="gap",
                      screen_backend="pallas")
     assert (res.gaps <= 1e-7).all()
+
+
+def test_path_counts_group_steps_inside_the_k_done_read(monkeypatch):
+    """``n_group_steps`` is what the compacted epoch blocks ran: per epoch
+    the slots up to the chunk holding the last live group, fewer than the
+    buffer's ``n_group_slots`` once a bucket of 32 holds at most 24 active
+    groups; and it rides in the ``k_done`` read, one per ``_inner_rounds``
+    call, with no read of its own."""
+    from repro.core import session as session_mod
+    from repro.core import solver as solver_mod
+    from repro.core.session import SGLSession, SolverConfig
+    from repro.obs import metrics as obs_metrics
+    from repro.obs import trace as ot
+
+    calls = []
+    inner = session_mod._inner_rounds
+
+    def logged(Xt, Lg, w, y, beta, feat_active, take, gmask, *rest):
+        out = inner(Xt, Lg, w, y, beta, feat_active, take, gmask, *rest)
+        calls.append((Lg * gmask, rest[3], out[1]))   # block_epochs, k
+        return out
+
+    monkeypatch.setattr(session_mod, "_inner_rounds", logged)
+    X, y, _, sizes = make_synthetic(n=30, p=200, n_groups=40, gamma1=3,
+                                    gamma2=3, seed=9)
+    prob = make_problem(X, y, sizes, tau=0.3)
+    steps0 = obs_metrics.REGISTRY.counter("solver.epoch_group_steps").value
+    slots0 = obs_metrics.REGISTRY.counter("solver.epoch_group_slots").value
+    ot.configure(enabled=True, sample_every=1)
+    ot.TRACER.reset()
+    try:
+        res = SGLSession(prob, SolverConfig(tol=1e-8, rule="gap")
+                         ).solve_path(T=5, delta=2.0)
+        k_done_reads = [r for r in ot.TRACER.records("read")
+                        if r["attrs"].get("what") == "k_done"]
+    finally:
+        ot.configure(enabled=False)
+        ot.TRACER.reset()
+
+    C = solver_mod._GROUP_CHUNK
+    steps = slots = 0
+    for live_L, block_epochs, k in calls:
+        live_L = np.asarray(live_L)
+        last = np.nonzero(live_L > 0)[0].max() + 1 if (live_L > 0).any() else 0
+        epochs = block_epochs * int(k)
+        steps += -(-last // C) * C * epochs
+        slots += live_L.size * epochs
+    assert calls and len(k_done_reads) == len(calls)
+    assert res.n_group_steps == steps > 0
+    assert res.n_group_slots == slots
+    assert res.n_group_steps < res.n_group_slots
+    assert obs_metrics.REGISTRY.counter(
+        "solver.epoch_group_steps").value - steps0 == steps
+    assert obs_metrics.REGISTRY.counter(
+        "solver.epoch_group_slots").value - slots0 == slots

@@ -16,7 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import SGLSession, SolverConfig, make_problem
 from repro.core.sgl import SGLProblem
-from repro.core.solver import _screen_round, resolve_backend
+from repro.core.solver import _inner_rounds, _screen_round, resolve_backend
 from repro.kernels import _util as kernel_util
 from repro.kernels.cases import kernel_cases
 from repro.rules import GapSafeRule
@@ -81,6 +81,22 @@ def test_screen_round_f64_xla_compiles(one_chip):
     args = _on(one_chip, (problem, S((G, ng), f64), S((), f64),
                           S((), f64)))
     compiled = _screen_round.lower(*args, rule=GapSafeRule(),
+                                   backend="xla").compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_epoch_block_f64_xla_compiles(one_chip):
+    """The f64 epoch block (live-bounded group loop inside the blocked
+    while loop) at the largest bucket the paper-synth path fills."""
+    n, G, ng = SHAPES["paper-synth"]
+    Gb = 256
+    f64 = jnp.float64
+    S = jax.ShapeDtypeStruct
+    args = _on(one_chip, (
+        S((Gb, n, ng), f64), S((Gb,), f64), S((Gb,), f64), S((n,), f64),
+        S((G, ng), f64), S((G, ng), jnp.bool_), S((Gb,), jnp.int64),
+        S((Gb,), f64), S((), f64), S((), f64), S((), f64)))
+    compiled = _inner_rounds.lower(*args, block_epochs=1, max_blocks=100,
                                    backend="xla").compile()
     assert "tpu_custom_call" not in compiled.as_text()
 
