@@ -16,16 +16,21 @@ group-level quantity a reduction over the trailing axis — the layout XLA/TPU
 wants — and exactly matches the paper's experiments (equal-size groups of 10
 and 7).  ``flatten``/``unflatten`` convert to the flat (p,) view.
 
-Everything here is pure and jit-compatible.
+Everything here but :func:`make_problem`, which is set-up and waits for its
+outputs, is pure and jit-compatible.
 """
 from __future__ import annotations
 
 import functools
+import time
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..obs import metrics as obs_metrics
+from ..obs import trace as obs_trace
 from .epsilon_norm import lam
 from .precision import one_minus
 
@@ -90,6 +95,12 @@ class SGLProblem(NamedTuple):
         return self.X.shape[2]
 
 
+_M_MAKE_PROBLEM_S = obs_metrics.REGISTRY.gauge(
+    "sgl.make_problem_s",
+    help="Seconds the last make_problem took, from entry until its outputs "
+         "were ready on the device")
+
+
 def _group_spectral_norms(Xg: jax.Array, n_iter: int = 50) -> jax.Array:
     """||X_g||_2 for each group via power iteration on X_g^T X_g.
 
@@ -125,41 +136,50 @@ def make_problem(
 
     ``group_sizes``: python sequence of ints summing to p (contiguous groups).
     ``w``: group weights; defaults to sqrt(n_g) (paper Section 7.1).
+
+    The grouped design is one device computation: one gather of a (G, ng)
+    column table, with the padding slots of smaller groups zeroed.  Returns
+    once every output is ready, inside a ``make_problem`` span, and sets
+    the ``sgl.make_problem_s`` gauge to the seconds that took.
     """
+    t0 = time.perf_counter()
     X_flat = jnp.asarray(X_flat)
     y = jnp.asarray(y, X_flat.dtype)
-    sizes = [int(s) for s in group_sizes]
+    sizes = np.asarray([int(s) for s in group_sizes], np.int64)
     n, p = X_flat.shape
-    assert sum(sizes) == p, (sum(sizes), p)
-    G = len(sizes)
-    ng = max(sizes)
+    if sizes.sum() != p:
+        raise ValueError(f"group sizes sum to {sizes.sum()}, not p={p}")
+    G = sizes.size
+    ng = int(sizes.max())
+    with obs_trace.span("make_problem") as sp:
+        sp.set("n", n).set("p", p).set("G", G).set("ng", ng)
+        sp.set("bytes", n * G * ng * X_flat.dtype.itemsize)
+        slot = np.arange(ng)
+        mask_np = slot[None, :] < sizes[:, None]
+        cols = np.where(mask_np, (np.cumsum(sizes) - sizes)[:, None] + slot, 0)
+        mask = jnp.asarray(mask_np)
+        Xg = jnp.where(mask, X_flat[:, cols], jnp.zeros((), X_flat.dtype))
 
-    Xg = jnp.zeros((n, G, ng), X_flat.dtype)
-    mask = jnp.zeros((G, ng), bool)
-    off = 0
-    for g, s in enumerate(sizes):
-        Xg = Xg.at[:, g, :s].set(X_flat[:, off : off + s])
-        mask = mask.at[g, :s].set(True)
-        off += s
+        if w is None:
+            w = jnp.sqrt(jnp.asarray(sizes, X_flat.dtype))
+        else:
+            w = jnp.asarray(w, X_flat.dtype)
 
-    if w is None:
-        w = jnp.sqrt(jnp.asarray(sizes, X_flat.dtype))
-    else:
-        w = jnp.asarray(w, X_flat.dtype)
-
-    Lg = _group_spectral_norms(Xg)
-    # Padded groups/columns: keep Lg > 0 guard at use sites.
-    col = jnp.linalg.norm(Xg, axis=0)  # (G, ng)
-    return SGLProblem(
-        X=Xg,
-        y=y,
-        w=w,
-        tau=jnp.asarray(tau, X_flat.dtype),
-        feat_mask=mask,
-        Lg=Lg,
-        Xnorm_col=col,
-        Xnorm_grp=jnp.sqrt(Lg),
-    )
+        Lg = _group_spectral_norms(Xg)
+        # Padded groups/columns: keep Lg > 0 guard at use sites.
+        col = jnp.linalg.norm(Xg, axis=0)  # (G, ng)
+        problem = jax.block_until_ready(SGLProblem(
+            X=Xg,
+            y=y,
+            w=w,
+            tau=jnp.asarray(tau, X_flat.dtype),
+            feat_mask=mask,
+            Lg=Lg,
+            Xnorm_col=col,
+            Xnorm_grp=jnp.sqrt(Lg),
+        ))
+    _M_MAKE_PROBLEM_S.set(time.perf_counter() - t0)
+    return problem
 
 
 def problem_from_grouped(

@@ -1,12 +1,13 @@
 """Dimension-faithful stand-in for the NCEP/NCAR Reanalysis 1 experiment.
 
 The paper's real dataset (monthly climate measurements, 1948-2015, 144x73
-grid, 7 variables per grid point => X in R^{814 x 73577}, y = air temperature
-near Dakar) is not redistributable offline.  This generator reproduces its
-*structure*: n monthly samples, G grid-point groups of 7 physical variables
-with strong within-group correlation, smooth spatial correlation across
-neighbouring grid points, seasonality + trend (then removed, as the paper's
-preprocessing does), and a target driven by a small set of nearby groups.
+grid, 7 variables per grid point, less the target point's own 7 => X in
+R^{814 x 73577}, y = air temperature near Dakar) is not redistributable
+offline.  This generator reproduces its *structure*: n monthly samples, G
+grid-point groups of 7 physical variables with strong within-group
+correlation, smooth spatial correlation across neighbouring grid points,
+seasonality + trend (then removed, as the paper's preprocessing does), and
+a target driven by a small set of nearby groups.
 """
 from __future__ import annotations
 
@@ -29,16 +30,29 @@ def make_climate_like(
     noise: float = 0.05,
     seed: int = 0,
     dtype=np.float64,
+    drop_point: tuple[int, int] | None = None,
 ):
     """Returns (X, y, beta_true, group_sizes).
 
-    Full-scale paper dims are n_lon=144, n_lat=73 (p = 73577 including the
-    target stub); defaults here are reduced for CPU tests, but any size works
-    (the benchmark uses larger grids).
+    One group of ``n_vars`` columns per grid point, the point (i_lon, i_lat)
+    being group ``i_lon * n_lat + i_lat``.  ``drop_point`` = (i_lon, i_lat)
+    leaves that point's group out of X, as the paper leaves out the target
+    point's own variables: the full grid is generated and preprocessed, then
+    those columns are removed, and the ground truth and y are drawn over the
+    groups that remain.
+
+    Full-scale paper dims are n_lon=144, n_lat=73 with ``drop_point`` set:
+    10,511 groups, p = 73,577 (73,584 without it).  The defaults here are
+    reduced for CPU tests, but any size works.
     """
     rng = np.random.default_rng(seed)
     G = n_lon * n_lat
     p = G * n_vars
+    if drop_point is not None:
+        i_lon, i_lat = (int(i) for i in drop_point)
+        if not (0 <= i_lon < n_lon and 0 <= i_lat < n_lat):
+            raise ValueError(f"drop_point {tuple(drop_point)} is off the "
+                             f"{n_lon} x {n_lat} grid")
     t = np.arange(n)
 
     # Latent smooth climate fields: low-rank spatial factors * AR(1) drivers.
@@ -82,6 +96,12 @@ def make_climate_like(
                   / ((t - t.mean()) ** 2).sum())
     X /= np.maximum(X.std(axis=0, keepdims=True), 1e-12)
 
+    if drop_point is not None:
+        g = i_lon * n_lat + i_lat
+        X = np.delete(X, np.s_[g * n_vars:(g + 1) * n_vars], axis=1)
+        G -= 1
+        p -= n_vars
+
     # Target: sparse group-structured ground truth near a "Dakar" location.
     beta = np.zeros(p)
     target_g = rng.choice(G, size=n_active_regions, replace=False)
@@ -92,4 +112,5 @@ def make_climate_like(
         )
     y = X @ beta + noise * rng.standard_normal(n)
     y -= y.mean()
-    return X.astype(dtype), y.astype(dtype), beta.astype(dtype), [n_vars] * G
+    return (X.astype(dtype, copy=False), y.astype(dtype), beta.astype(dtype),
+            [n_vars] * G)

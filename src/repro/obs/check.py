@@ -15,12 +15,12 @@ static-analysis gate, so CI treats both gates identically):
 * **OB002 — span coverage.**  Every span site declared in
   :data:`repro.obs.trace.SPAN_SITES` must actually fire on a smoke
   path: a tiny two-request serve sequence (pallas backends, warm-start
-  second request) that traverses request → coalesce → store → cache →
-  warm_eval → path → lambda → round → epoch_block, read, masks, gather.  A
-  site that never fires means its instrumentation was dropped in a
-  refactor — exactly the regression this gate exists to catch.  The
-  tracer's *exact* per-site counters are used (sampling only thins the
-  recorded span buffer, never the counts).
+  second request) that traverses make_problem, then request → coalesce →
+  store → cache → warm_eval → path → lambda → round → epoch_block, read,
+  masks, gather.  A site that never fires means its instrumentation was
+  dropped in a refactor — exactly the regression this gate exists to
+  catch.  The tracer's *exact* per-site counters are used (sampling only
+  thins the recorded span buffer, never the counts).
 
 Both passes accept injected inputs (``schema=``, ``counts=``) so tests
 can prove each finding fires on a seeded fixture without monkey-patching

@@ -3,6 +3,7 @@ the profiler trace.
 
 Span taxonomy (declared in :data:`SPAN_SITES`, audited by OB002)::
 
+    make_problem             one grouped design built (set-up, not a path)
     serve.request            one coalesced group through _serve_group
       serve.coalesce         queue drain + value-digest grouping window
       serve.store            certificate-store lookup / publish
@@ -63,6 +64,8 @@ _profiling = _TraceAnnotation.is_enabled
 #: Declared span sites: name -> where it fires.  ``repro.obs --check``
 #: (OB002) runs a smoke path and fails if any of these never fired.
 SPAN_SITES: Dict[str, str] = {
+    "make_problem": "core/sgl.py:make_problem — one grouped design built, "
+                    "until its outputs are ready (n, p, G, ng, bytes)",
     "serve.request": "serve/server.py:_serve_group — one coalesced group",
     "serve.coalesce": "serve/server.py:_worker_loop — drain+group window",
     "serve.store": "serve/server.py — certificate store lookup/publish",

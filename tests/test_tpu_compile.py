@@ -21,9 +21,10 @@ from repro.kernels import _util as kernel_util
 from repro.kernels.cases import kernel_cases
 from repro.rules import GapSafeRule
 
-# (n, G, ng): the paper's synthetic problem and the full NCEP/NCAR climate
-# design (144 x 73 grid points x 7 variables).
-SHAPES = {"paper-synth": (100, 1000, 10), "climate-full": (814, 10512, 7)}
+# (n, G, ng): the paper's synthetic problem and the NCEP/NCAR climate design
+# at its published width (144 x 73 grid points less the target point, in
+# groups of 7 variables: p = 73,577).
+SHAPES = {"paper-synth": (100, 1000, 10), "climate-full": (814, 10511, 7)}
 KERNELS = sorted(kernel_cases(8, 8, 8, np.float32))
 
 
