@@ -460,15 +460,24 @@ def resolve_solver_backend(backend: str, dtype) -> str:
     return resolve_backend(backend, dtype, what="solver backend")
 
 
+def _design_2d(problem: SGLProblem) -> jax.Array:
+    """The grouped design as one (n, G*ng) matrix, reshaped inside the
+    calling program.  XLA emulates f64 dots on the TPU, and a contraction
+    over the two axes (G, ng) of the 3-D design costs it several times the
+    same contraction over one axis of this matrix, at the same precision."""
+    n, G, ng = problem.X.shape
+    return problem.X.reshape(n, G * ng)
+
+
 def _corr_grouped(problem: SGLProblem, v: jax.Array, backend: str,
                   xt_pre: Optional[jax.Array]) -> jax.Array:
     """Backend-routed grouped correlation X^T v — the shared skeleton's one
     correlation primitive.  ``"pallas"`` runs the corr-only Pallas matvec
     over the persistent transposed design (on-the-fly transposes are
-    audit-counted); ``"xla"`` the plain einsum."""
+    audit-counted); ``"xla"`` one rank-2 product ``v @ X2``."""
     if backend == "pallas":
         return kops.screening_corr_grouped(problem.X, v, xt_pre=xt_pre)
-    return jnp.einsum("ngk,n->gk", problem.X, v)
+    return (v @ _design_2d(problem)).reshape(problem.G, problem.ng)
 
 
 def check_rule_loss(rule: ScreeningRule, loss: Loss) -> None:
@@ -519,9 +528,14 @@ def _screen_round(problem: SGLProblem, beta: jax.Array, lam_: jax.Array,
     round.  For rules that do not screen dynamically the masks are
     all-true; rounds from unsafe rules come back flagged ``safe=False``.
 
+    The round passes over the design exactly twice, each time as a rank-2
+    product on ``X.reshape(n, G*ng)`` (:func:`_design_2d`): ``X beta``,
+    and ``X^T resid`` (plus a dynamic rule's ``X^T center``).  The lsq gap
+    is taken from the round's own residual.
+
     ``backend="pallas"`` computes the hot X^T resid correlation through the
     corr-only Pallas matvec kernel and the SGL dual norm through the Pallas
-    bisection kernel (kernels.ops); ``"xla"`` uses plain einsums.
+    bisection kernel (kernels.ops); ``"xla"`` uses plain products.
     ``xt_pre`` is the persistent (p, n) transposed design from
     :func:`repro.kernels.ops.prepare_transposed` — without it every
     Pallas-backed round materialises a fresh transposed copy of X.
@@ -529,15 +543,14 @@ def _screen_round(problem: SGLProblem, beta: jax.Array, lam_: jax.Array,
     ``loss`` (static): a :class:`repro.losses.Loss`, or None for the
     historical squared loss.  The skeleton generalizes by swapping the
     residual for ``rho = -grad F(X beta)`` (Eq. 15 is otherwise verbatim)
-    and the gap for the loss's primal/dual pair; the lsq branch keeps the
-    original arithmetic untouched so the default loss stays bit-identical.
-    The sphere test sees the loss only through ``RuleState.nu``.
+    and the gap for the loss's primal/dual pair.  The sphere test sees the
+    loss only through ``RuleState.nu``.
     """
     lsq = loss is None or loss.name == "lsq"
+    z = _design_2d(problem) @ beta.reshape(-1)
     if lsq:
-        resid = problem.y - jnp.einsum("ngk,gk->n", problem.X, beta)
+        resid = problem.y - z
     else:
-        z = jnp.einsum("ngk,gk->n", problem.X, beta)
         resid = loss.neg_grad(problem.y, z)   # generalized residual rho
     corr = _corr_grouped(problem, resid, backend, xt_pre)
     if backend == "pallas":
@@ -547,12 +560,15 @@ def _screen_round(problem: SGLProblem, beta: jax.Array, lam_: jax.Array,
     dual_norm = jnp.max(terms)
     scale = jnp.maximum(lam_, dual_norm)
     theta = resid / scale
+    penalty = lam_ * sgl.sgl_norm(beta, problem.tau, problem.w)
     if lsq:
-        gap = sgl.duality_gap(problem, beta, theta, lam_)
+        # sgl.primal from the round's own residual: its grouped X beta
+        # would be a third pass over the design.
+        gap = (0.5 * jnp.sum(resid * resid) + penalty
+               - sgl.dual(problem, theta, lam_))
     else:
-        primal = loss.value(problem.y, z) + lam_ * sgl.sgl_norm(
-            beta, problem.tau, problem.w)
-        gap = primal - loss.dual_obj(problem.y, theta, lam_)
+        gap = (loss.value(problem.y, z) + penalty
+               - loss.dual_obj(problem.y, theta, lam_))
 
     if rule.is_dynamic:
         state = RuleState(

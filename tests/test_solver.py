@@ -3,8 +3,9 @@
 Hypothesis-based property tests live in test_properties.py; path-engine
 tests (sequential screening, cache carrying) in test_path.py.
 """
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import (
@@ -141,3 +142,90 @@ def test_lambda_grid_matches_paper():
     assert g[0] == 100.0
     np.testing.assert_allclose(g[-1], 100.0 * 10 ** -3.0)
     assert len(g) == 100
+
+
+# The full certified round against the grouped-einsum formulas: groups of
+# 7 (climate), of 10 (synth) and ragged, for the GAP rule on lsq and
+# logistic and for a dynamic rule whose center needs a second correlation.
+ROUND_SIZES = {"g7": [7] * 9, "g10": [10] * 8, "ragged": [3, 7, 5, 10, 2, 6]}
+ROUND_RULES = [("gap", "lsq"), ("gap", "logistic"), ("dynamic", "lsq")]
+
+
+def _round_problem(sizes, loss_name, seed=3):
+    rng = np.random.default_rng(seed)
+    n, p = 40, sum(sizes)
+    X = rng.standard_normal((n, p))
+    if loss_name == "logistic":
+        y = (rng.standard_normal(n) > 0).astype(np.float64)
+    else:
+        y = X[:, :4] @ np.array([2.0, -1.5, 1.0, 0.5]) + 0.1 * rng.standard_normal(n)
+    prob = make_problem(X, y, sizes, tau=0.3)
+    beta = (0.01 * rng.standard_normal(prob.feat_mask.shape)
+            * np.asarray(prob.feat_mask))
+    beta[2:, :] = 0.0          # a sparse primal point: two live groups
+    return prob, jnp.asarray(beta)
+
+
+@pytest.mark.parametrize("rule_name,loss_name", ROUND_RULES)
+@pytest.mark.parametrize("shape", sorted(ROUND_SIZES))
+def test_screen_round_matches_grouped_einsum_formulas(shape, rule_name,
+                                                      loss_name):
+    from repro.core import screening as scr
+    from repro.core import sgl
+    from repro.core.solver import _screen_round
+    from repro.losses import resolve_loss
+    from repro.rules import RuleState, get_rule
+
+    prob, beta = _round_problem(ROUND_SIZES[shape], loss_name)
+    loss, rule = resolve_loss(loss_name), get_rule(rule_name)
+    lam_max = sgl.lambda_max_loss(prob, loss)
+    lam_ = 0.9 * lam_max
+    rr, resid, terms = _screen_round(prob, beta, lam_, lam_max, rule=rule,
+                                     backend="xla", loss=loss)
+
+    theta = sgl.dual_scale_loss(prob, loss, beta, lam_)
+    gap = sgl.duality_gap_loss(prob, loss, beta, theta, lam_)
+    corr = jnp.einsum("ngk,n->gk", prob.X, resid)
+    scale = jnp.maximum(lam_, sgl.sgl_dual_norm(corr, prob.tau, prob.w))
+    state = RuleState(problem=prob, beta=beta, resid=resid, corr=corr,
+                      scale=scale, theta=theta, gap=gap, lam=lam_,
+                      lam_max=lam_max, nu=float(loss.nu))
+    center, radius, _ = rule.center_and_radius(state)
+    ref = scr.screen_with_corr(
+        prob, scr.Sphere(center, radius),
+        jnp.einsum("ngk,n->gk", prob.X, center))
+
+    assert abs(float(rr.gap - gap)) <= 1e-12 * abs(float(gap))
+    np.testing.assert_allclose(np.asarray(rr.theta), np.asarray(theta),
+                               rtol=0, atol=1e-12 * float(jnp.max(
+                                   jnp.abs(theta))))
+    np.testing.assert_allclose(
+        np.asarray(terms), np.asarray(sgl.sgl_dual_norm_terms(
+            corr, prob.tau, prob.w)), rtol=1e-12)
+    g_ref = np.asarray(ref.group_active)
+    np.testing.assert_array_equal(np.asarray(rr.group_active), g_ref)
+    np.testing.assert_array_equal(np.asarray(rr.feat_active),
+                                  np.asarray(ref.feat_active))
+    assert 0 < g_ref.sum() < g_ref.size   # the tests had something to cut
+
+
+def test_screen_round_passes_over_design_twice_as_rank2_products():
+    """The lsq round touches X in exactly two dot_generals, both rank 2:
+    X beta and X^T resid.  A grouped (3-D) contraction or a third pass
+    (a gap recomputing X beta) fails this."""
+    from repro.analysis.jaxpr_lints import iter_eqns
+    from repro.core.solver import _screen_round
+    from repro.rules import GapSafeRule
+
+    prob, beta = _round_problem(ROUND_SIZES["g7"], "lsq")
+    lam_max = lambda_max(prob)
+    jaxpr = jax.make_jaxpr(
+        lambda p, b, l, lm: _screen_round(p, b, l, lm, rule=GapSafeRule(),
+                                          backend="xla"))(
+        prob, beta, 0.7 * lam_max, lam_max)
+    design = prob.X.size
+    on_x = [e for e in iter_eqns(jaxpr.jaxpr)
+            if e.primitive.name == "dot_general"
+            and any(np.prod(v.aval.shape) == design for v in e.invars)]
+    assert len(on_x) == 2
+    assert all(v.aval.ndim <= 2 for e in on_x for v in e.invars)

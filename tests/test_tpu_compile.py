@@ -71,8 +71,9 @@ def test_kernel_compiles_f32_under_x64(one_chip, as_tpu, name, cell):
     assert "tpu_custom_call" in text
 
 
-def test_screen_round_f64_xla_compiles(one_chip):
-    n, G, ng = SHAPES["paper-synth"]
+@pytest.mark.parametrize("cell", sorted(SHAPES))
+def test_screen_round_f64_xla_compiles(one_chip, cell):
+    n, G, ng = SHAPES[cell]
     f64 = jnp.float64
     S = jax.ShapeDtypeStruct
     problem = SGLProblem(
